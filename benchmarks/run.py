@@ -890,14 +890,9 @@ def bench_serve_kernel() -> None:
                 jnp_.zeros((n_slots, eng._table_pages), jnp_.int32)]
         if eng.fused:
             args.append(jnp_.ones((n_slots,), jnp_.int32))
-        try:
-            ca = eng._decode_fn.lower(*args).compile().cost_analysis()
-            if isinstance(ca, (list, tuple)):
-                ca = ca[0] if ca else {}
-            return {"flops": float(ca.get("flops", 0.0)),
-                    "bytes_accessed": float(ca.get("bytes accessed", 0.0))}
-        except Exception:                      # backend without analysis
-            return {"flops": 0.0, "bytes_accessed": 0.0}
+        ca = eng._decode_fn.lower(*args).compile().cost_analysis()
+        return {"flops": float(ca["flops"]),
+                "bytes_accessed": float(ca["bytes accessed"])}
 
     fused_eng, unfused_eng = make_engine(True), make_engine(False)
     fused_best = unfused_best = None
@@ -916,7 +911,7 @@ def bench_serve_kernel() -> None:
 
     fused_cost = step_cost(fused_eng)
     unfused_cost = step_cost(unfused_eng)
-    active = impl_mod.resolve_runnable() == "pallas"
+    active = impl_mod.resolve() == "pallas"
     fused_eng.shutdown()
     unfused_eng.shutdown()
 
@@ -1886,6 +1881,8 @@ def main() -> None:
                     help="measured samples per serve scenario; feeds the "
                     "mean/cv/ci95 variance fields in BENCH_serve.json")
     args = ap.parse_args()
+    from repro.compile_cache import configure
+    configure()
     QUICK = args.quick
     SAMPLES = max(1, args.samples)
     benches = QUICK_BENCHES if args.quick else ALL_BENCHES

@@ -1,97 +1,29 @@
-"""Version-compat shims for JAX API drift.
+"""Thin helpers over the installed JAX (0.9.0) that several modules share.
 
-The repo runs on whatever JAX build the image bakes in; these helpers
-paper over the API moves between the 0.4.x line and newer releases so
-the same source works on both:
-
-* ``shard_map`` graduated from ``jax.experimental.shard_map`` to
-  ``jax.shard_map``, and its replication-check kwarg was renamed
-  ``check_rep`` → ``check_vma`` along the way.
-* ``jax.sharding.AxisType`` (explicit mesh axis types) does not exist on
-  older builds, where ``jax.make_mesh`` also rejects an ``axis_types``
-  kwarg; meshes there are implicitly Auto on every axis, which is the
-  behaviour we want anyway.
-* Pallas: ``jax.experimental.pallas`` (and its TPU dialect) is the one
-  import the canary CI leg can break silently — experimental namespaces
-  move without deprecation cycles. Kernel modules import ``pl``/``pltpu``
-  from here instead of from ``jax.experimental`` directly, and the ops
-  wrappers consult :func:`pallas_available` so a pallas-less build
-  degrades to the ``xla`` reference path with a visible warning instead
-  of an ImportError at collection time.
+* :func:`make_mesh` — ``jax.make_mesh`` with every axis ``AxisType.Auto``
+  unless the caller says otherwise: the repo's logical sharding rules rely
+  on the partitioner propagating shardings.
+* :func:`shard_map` — ``jax.shard_map``.
+* :func:`cost_analysis` — the compiled program's flat cost dict.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Any
 
 import jax
-
-try:  # new-style top-level export
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # pinned 0.4.x line
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SHARD_MAP_PARAMS = frozenset(
-    inspect.signature(_shard_map_impl).parameters)
+from jax.sharding import AxisType
 
 
 def shard_map(f, mesh=None, in_specs=None, out_specs=None, *,
               check_vma: bool = True, **kwargs: Any):
-    """``shard_map`` accepting the ``check_vma`` spelling on every JAX."""
-    if "check_vma" in _SHARD_MAP_PARAMS:
-        kwargs["check_vma"] = check_vma
-    elif "check_rep" in _SHARD_MAP_PARAMS:
-        kwargs["check_rep"] = check_vma
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kwargs)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kwargs)
 
 
 def cost_analysis(compiled) -> dict:
-    """Flat cost-analysis dict on every JAX build.
-
-    Older builds return a one-element list of per-program dicts from
-    ``Compiled.cost_analysis()``; newer ones return the dict directly.
-    """
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
-try:  # canary-sensitive: experimental namespaces move without notice
-    from jax.experimental import pallas as pl
-except ImportError:  # pragma: no cover - exercised only on broken canaries
-    pl = None
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
-
-def pallas_available() -> bool:
-    """True when both ``pallas`` and its TPU dialect import cleanly."""
-    return pl is not None and pltpu is not None
-
-
-def require_pallas():
-    """Return ``(pl, pltpu)`` or raise a targeted ImportError.
-
-    Kernel entry points call this at trace time so a pallas-less build
-    fails with an actionable message (use the ``xla`` impl) instead of an
-    AttributeError on a ``None`` module.
-    """
-    if not pallas_available():
-        raise ImportError(
-            "jax.experimental.pallas(.tpu) is unavailable on this JAX "
-            "build; select the 'xla' kernel impl "
-            "(repro.kernels.impl.use_impl) or pin a JAX with Pallas")
-    return pl, pltpu
+    return compiled.cost_analysis()
 
 
 def make_mesh(shape, axes, **kwargs: Any):
-    """``jax.make_mesh`` that passes Auto ``axis_types`` only where the
-    build supports them (older builds are implicitly Auto)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        kwargs.setdefault("axis_types", (axis_type.Auto,) * len(axes))
+    kwargs.setdefault("axis_types", (AxisType.Auto,) * len(axes))
     return jax.make_mesh(shape, axes, **kwargs)

@@ -23,10 +23,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-# canary-sensitive imports route through compat: ``pl``/``pltpu`` are None
-# on a pallas-less build and flash_attention() raises a targeted error at
-# trace time (the ops wrapper never gets here — it downgrades to 'xla')
-from repro.compat import pl, pltpu, require_pallas
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -108,7 +106,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     block_q: int = 512, block_k: int = 512,
                     interpret: bool = False) -> jax.Array:
     """q: (B, Sq, H, D);  k, v: (B, Sk, KV, D). Returns (B, Sq, H, D)."""
-    require_pallas()
     B, Sq, H, D = q.shape
     _, Sk, KV, _ = k.shape
     groups = H // KV

@@ -1,25 +1,23 @@
 """Kernel implementation selection.
 
 Models call kernel ``ops`` wrappers; the active implementation is resolved
-per-call → per-context override → backend default:
+per-call → :func:`use_impl` override → backend default:
 
-* ``"pallas"``           — real TPU lowering (the deployment target),
+* ``"pallas"``           — real TPU lowering (the deployment target, and
+                           the default wherever JAX's backend is a TPU),
 * ``"pallas_interpret"`` — kernel body interpreted on CPU (tests),
-* ``"xla"``              — the pure-jnp reference path (CPU smoke tests and
-                           the dry-run/roofline compiles, which target the
-                           CPU backend where TPU Pallas cannot lower).
+* ``"xla"``              — the pure-jnp reference path (the CPU default,
+                           and the dry-run/roofline compiles, which target
+                           the CPU backend where TPU Pallas cannot lower).
 """
 from __future__ import annotations
 
 import contextlib
-import threading
-import warnings
 
 import jax
 
-_TLS = threading.local()
 VALID = ("xla", "pallas", "pallas_interpret")
-_WARNED_NO_PALLAS = False
+_override: str | None = None
 
 
 def backend_default() -> str:
@@ -28,36 +26,20 @@ def backend_default() -> str:
 
 def resolve(impl: str | None = None) -> str:
     if impl is None:
-        impl = getattr(_TLS, "impl", None) or backend_default()
+        impl = _override or backend_default()
     if impl not in VALID:
         raise ValueError(f"unknown kernel impl {impl!r}; expected {VALID}")
     return impl
 
 
-def resolve_runnable(impl: str | None = None) -> str:
-    """:func:`resolve`, then downgrade ``pallas*`` → ``xla`` (one visible
-    warning) when the build lacks Pallas — the canary-safe entry point
-    for ops wrappers, so a JAX that moved ``jax.experimental.pallas``
-    degrades to the reference path instead of breaking imports."""
-    from repro import compat
-    impl = resolve(impl)
-    if impl != "xla" and not compat.pallas_available():
-        global _WARNED_NO_PALLAS
-        if not _WARNED_NO_PALLAS:
-            warnings.warn(
-                "jax.experimental.pallas is unavailable on this JAX "
-                "build; kernel ops fall back to the 'xla' reference "
-                "implementation", RuntimeWarning, stacklevel=3)
-            _WARNED_NO_PALLAS = True
-        return "xla"
-    return impl
-
-
 @contextlib.contextmanager
 def use_impl(impl: str):
-    prev = getattr(_TLS, "impl", None)
-    _TLS.impl = impl
+    """Override the implementation for every thread while the block runs:
+    a ``ServeClient`` traces its steps on its own loop thread, which a
+    thread-local override would miss."""
+    global _override
+    prev, _override = _override, resolve(impl)
     try:
         yield
     finally:
-        _TLS.impl = prev
+        _override = prev

@@ -32,19 +32,23 @@ prefix-cache hit.
 On-device page-table memory layout (pinned contract, shared with
 ``ref.py`` and ``serve.kv_cache.PagePool``):
 
-* pool (one layer): ``(total_pages + 1, page_size, KV, head_dim)`` —
-  page index ``total_pages`` is the scratch ("null") page; table padding
-  points at it so idle slots and table tails read/write garbage there.
+* pool (one layer): ``(total_pages + 1, KV, page_size, head_dim)`` —
+  page-major, kv-head-minor-to-page, so one (page, kv head) block is a
+  whole ``(page_size, head_dim)`` tile. Page index ``total_pages`` is the
+  scratch ("null") page; table padding points at it so idle slots and
+  table tails read/write garbage there.
 * ``tables (S, T)`` int32 — entry ``p`` holds the pool page owning
   absolute token positions ``[p*page_size, (p+1)*page_size)``.
 * ``positions (S,)`` int32 — absolute position of window row 0.
 * ``n_valid (S,)`` int32 — rows actually written (0 = idle slot).
 
-TPU shaping notes: blocks are one page × one kv head × head_dim, with
-``W*G`` query rows per grid step; at production sizes pick page_size and
-head_dim as multiples of the (8, 128) tile. Every visited page is
-re-written (read-modify-write through the alias), trading one page of
-write bandwidth per gathered page for the one-kernel structure; a
+TPU shaping: every block's last two dims are whole array dims — pool
+blocks ``(page_size, head_dim)``, query/output blocks ``(W*G, head_dim)``
+(the ``G`` query heads of one kv head are flattened into rows outside the
+kernel), new-KV blocks ``(W, head_dim)`` — which the TPU tiling rule
+accepts at any page size and head dim. Every visited page is re-written
+(read-modify-write through the alias), trading one page of write
+bandwidth per gathered page for the one-kernel structure; a
 write-window-only output spec is the follow-up optimization.
 """
 from __future__ import annotations
@@ -54,8 +58,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-
-from repro.compat import pl, pltpu, require_pallas
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -79,33 +83,33 @@ def _paged_kernel(tab_ref, pos_ref, nv_ref,          # scalar prefetch
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    k = kp_ref[0, :, 0, :]                               # (ps, hd)
-    v = vp_ref[0, :, 0, :]
+    k = kp_ref[0, 0]                                     # (ps, hd)
+    v = vp_ref[0, 0]
     kn = kn_ref[0, 0]                                    # (W, hd)
     vn = vn_ref[0, 0]
 
-    # accept-masked in-register KV insert: window row j (absolute position
-    # pos + j) lands at page offset pos + j - page_start when that offset
-    # is inside this page AND j < n_valid. One-hot contraction keeps the
-    # select vectorized (TPU wants 2D iota).
-    jj = jax.lax.broadcasted_iota(jnp.int32, (W, ps), 0)
-    tt = jax.lax.broadcasted_iota(jnp.int32, (W, ps), 1)
-    oh = ((pos + jj - page_start) == tt) & (jj < nv)     # (W, ps)
-    hit = oh.any(axis=0)                                 # (ps,)
-    ohf = oh.astype(kn.dtype)
-    dot_tw = (((0,), (0,)), ((), ()))                    # contract j axis
-    k = jnp.where(hit[:, None], jax.lax.dot_general(ohf, kn, dot_tw), k)
-    v = jnp.where(hit[:, None], jax.lax.dot_general(ohf, vn, dot_tw), v)
+    # accept-masked in-register KV insert: page row t receives window row
+    # j (absolute position pos + j) when pos + j - page_start == t AND
+    # j < n_valid. A (ps, W) one-hot matmul keeps the select vectorized;
+    # 0/1 weights make it exact.
+    tt = jax.lax.broadcasted_iota(jnp.int32, (ps, W), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (ps, W), 1)
+    ohf = (((pos + jj - page_start) == tt) & (jj < nv)).astype(jnp.float32)
+    hit = jnp.max(ohf, axis=1, keepdims=True) > 0.0      # (ps, 1)
+    k = jnp.where(hit, jnp.dot(ohf, kn.astype(jnp.float32)).astype(k.dtype),
+                  k)
+    v = jnp.where(hit, jnp.dot(ohf, vn.astype(jnp.float32)).astype(v.dtype),
+                  v)
 
     # unconditional writeback: the output block aliases the pool, so
     # untouched pages round-trip their own content and inserted rows land
     # in place (identical stores for pages shared across slots; the null
     # page collects garbage by contract)
-    ko_ref[0, :, 0, :] = k
-    vo_ref[0, :, 0, :] = v
+    ko_ref[0, 0] = k
+    vo_ref[0, 0] = v
 
     def _attend():
-        qf = q_ref[0, 0].reshape(W * G, -1).astype(jnp.float32)
+        qf = q_ref[0, 0].astype(jnp.float32)             # (W*G, hd)
         kf = k.astype(jnp.float32)
         sc = jax.lax.dot_general(
             qf, kf, (((1,), (1,)), ((), ()))) * scale    # (W*G, ps)
@@ -123,8 +127,8 @@ def _paged_kernel(tab_ref, pos_ref, nv_ref,          # scalar prefetch
         alpha = jnp.exp(m_prev - m_new)
         alpha = jnp.where(m_prev <= NEG_INF, 0.0, alpha)
         l_scr[...] = l_scr[...] * alpha + jnp.sum(pe, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            pe, v.astype(jnp.float32), (((1,), (0,)), ((), ())))
+        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
+            pe, v.astype(jnp.float32))
         m_scr[...] = m_new
 
     # idle slots (nv == 0) and pages entirely in the future (null-padded
@@ -135,7 +139,7 @@ def _paged_kernel(tab_ref, pos_ref, nv_ref,          # scalar prefetch
     @pl.when(p == n_p - 1)
     def _finish():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l).reshape(W, G, -1).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def paged_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
@@ -146,40 +150,40 @@ def paged_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Shapes as in ``ref.paged_attention``; returns (out, new_k, new_v)
     with the new pool arrays aliased in place over the inputs."""
-    require_pallas()
     S, W, H, hd = q.shape
-    P1, ps, KV, _ = k_pages.shape
+    P1, KV, ps, _ = k_pages.shape
     assert ps == page_size, (ps, page_size)
     T = tables.shape[1]
     G = H // KV
     scale = hd ** -0.5 if scale is None else scale
 
-    # head-major layouts so one (slot, kv-head) grid step owns one block
-    qt = q.reshape(S, W, KV, G, hd).transpose(0, 2, 1, 3, 4)
+    # head-major layouts so one (slot, kv-head) grid step owns one block;
+    # the G query heads of a kv head become rows w*G + g
+    qt = q.reshape(S, W, KV, G, hd).transpose(0, 2, 1, 3, 4).reshape(
+        S, KV, W * G, hd)
     knt = k_new.transpose(0, 2, 1, 3)                    # (S, KV, W, hd)
     vnt = v_new.transpose(0, 2, 1, 3)
 
+    def _slot_map(s, h, p, tab, pos, nv):
+        return (s, h, 0, 0)
+
     def _page_map(s, h, p, tab, pos, nv):
-        return (tab[s, p], 0, h, 0)
+        return (tab[s, p], h, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(S, KV, T),
         in_specs=[
-            pl.BlockSpec((1, 1, W, G, hd),
-                         lambda s, h, p, tab, pos, nv: (s, h, 0, 0, 0)),
-            pl.BlockSpec((1, 1, W, hd),
-                         lambda s, h, p, tab, pos, nv: (s, h, 0, 0)),
-            pl.BlockSpec((1, 1, W, hd),
-                         lambda s, h, p, tab, pos, nv: (s, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd), _page_map),
-            pl.BlockSpec((1, ps, 1, hd), _page_map),
+            pl.BlockSpec((1, 1, W * G, hd), _slot_map),
+            pl.BlockSpec((1, 1, W, hd), _slot_map),
+            pl.BlockSpec((1, 1, W, hd), _slot_map),
+            pl.BlockSpec((1, 1, ps, hd), _page_map),
+            pl.BlockSpec((1, 1, ps, hd), _page_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, W, G, hd),
-                         lambda s, h, p, tab, pos, nv: (s, h, 0, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd), _page_map),
-            pl.BlockSpec((1, ps, 1, hd), _page_map),
+            pl.BlockSpec((1, 1, W * G, hd), _slot_map),
+            pl.BlockSpec((1, 1, ps, hd), _page_map),
+            pl.BlockSpec((1, 1, ps, hd), _page_map),
         ],
         scratch_shapes=[
             pltpu.VMEM((W * G, 1), jnp.float32),
@@ -192,7 +196,7 @@ def paged_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
                           window=W, groups=G),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((S, KV, W, G, hd), q.dtype),
+            jax.ShapeDtypeStruct((S, KV, W * G, hd), q.dtype),
             jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
             jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
         ],
@@ -200,5 +204,7 @@ def paged_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
         # prefetch args: k_pages is operand 6, v_pages operand 7)
         input_output_aliases={6: 1, 7: 2},
         interpret=interpret,
+        name="paged_attention",
     )(tables, positions, n_valid, qt, knt, vnt, k_pages, v_pages)
-    return o.transpose(0, 2, 1, 3, 4).reshape(S, W, H, hd), nk, nv_out
+    o = o.reshape(S, KV, W, G, hd).transpose(0, 2, 1, 3, 4)
+    return o.reshape(S, W, H, hd), nk, nv_out

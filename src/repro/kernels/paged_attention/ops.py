@@ -1,10 +1,8 @@
 """Dispatching wrapper for fused paged attention: xla | pallas | interpret.
 
-Same canary-safe structure as ``flash_attention.ops``: the Pallas kernel
-module is only imported after :func:`repro.kernels.impl.resolve_runnable`
-confirms the build has ``jax.experimental.pallas``; otherwise the call
-runs the pure-jnp reference (identical semantics, including the in-pool
-scatter), with the one-time downgrade warning.
+``xla`` runs the pure-jnp reference (identical semantics, including the
+in-pool scatter); the Pallas kernel serves ``pallas`` and
+``pallas_interpret``.
 """
 from __future__ import annotations
 
@@ -13,7 +11,7 @@ from typing import Optional, Tuple
 import jax
 
 from repro.kernels import impl as impl_mod
-from repro.kernels.paged_attention import ref
+from repro.kernels.paged_attention import kernel, ref
 
 
 def paged_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
@@ -26,16 +24,15 @@ def paged_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     """Fused gather→attend→write over the paged KV pool.
 
     Shapes: q ``(S, W, H, hd)``, k_new/v_new ``(S, W, KV, hd)``,
-    k_pages/v_pages ``(P+1, ps, KV, hd)``, tables ``(S, T)`` int32,
+    k_pages/v_pages ``(P+1, KV, ps, hd)``, tables ``(S, T)`` int32,
     positions/n_valid ``(S,)`` int32. Returns
     ``(out (S, W, H, hd), new_k_pages, new_v_pages)``.
     """
-    impl = impl_mod.resolve_runnable(impl)
+    impl = impl_mod.resolve(impl)
     if impl == "xla":
         return ref.paged_attention(
             q, k_new, v_new, k_pages, v_pages, tables, positions, n_valid,
             page_size=page_size, scale=scale)
-    from repro.kernels.paged_attention import kernel
     return kernel.paged_attention(
         q, k_new, v_new, k_pages, v_pages, tables, positions, n_valid,
         page_size=page_size, scale=scale,

@@ -9,7 +9,7 @@ with per-query-row causal masking by absolute position — fp32 scores,
 
 On-device page-table layout (the contract `kernel.py` pins too):
 
-* pool (one layer): ``(total_pages + 1, page_size, KV, head_dim)``;
+* pool (one layer): ``(total_pages + 1, KV, page_size, head_dim)``;
   index ``total_pages`` is the scratch ("null") page.
 * ``tables (S, T)``: entry ``p`` of a slot's row is the pool page holding
   absolute positions ``[p*page_size, (p+1)*page_size)``; entries past the
@@ -35,13 +35,13 @@ def paged_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
                     scale: float | None = None
                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """q: (S, W, H, hd); k_new/v_new: (S, W, KV, hd);
-    k_pages/v_pages: (P+1, ps, KV, hd); tables: (S, T) int32;
+    k_pages/v_pages: (P+1, KV, ps, hd); tables: (S, T) int32;
     positions/n_valid: (S,) int32.
 
     Returns ``(out (S, W, H, hd), new_k_pages, new_v_pages)``.
     """
     S, W, H, hd = q.shape
-    P1, ps, KV, _ = k_pages.shape
+    P1, KV, ps, _ = k_pages.shape
     T = tables.shape[1]
     G = H // KV
     null = P1 - 1
@@ -58,16 +58,14 @@ def paged_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     page = jnp.take_along_axis(tables, entry, axis=1)       # (S, W)
     valid = offs[None, :] < n_valid[:, None]
     page = jnp.where(valid, page, null)
-    row = (page * ps + pos_j % ps).reshape(-1)              # flat pool row
-    new_k = k_pages.reshape(P1 * ps, KV, hd).at[row].set(
-        k_new.reshape(S * W, KV, hd)).reshape(P1, ps, KV, hd)
-    new_v = v_pages.reshape(P1 * ps, KV, hd).at[row].set(
-        v_new.reshape(S * W, KV, hd)).reshape(P1, ps, KV, hd)
+    page, row = page.reshape(-1), (pos_j % ps).reshape(-1)
+    new_k = k_pages.at[page, :, row].set(k_new.reshape(S * W, KV, hd))
+    new_v = v_pages.at[page, :, row].set(v_new.reshape(S * W, KV, hd))
 
     # ---- gather each slot's contiguous view and attend (grouped GQA,
     # exactly decode_attention's math on the gathered cache)
-    gk = new_k[tables].reshape(S, T * ps, KV, hd)
-    gv = new_v[tables].reshape(S, T * ps, KV, hd)
+    gk = new_k[tables].swapaxes(2, 3).reshape(S, T * ps, KV, hd)
+    gv = new_v[tables].swapaxes(2, 3).reshape(S, T * ps, KV, hd)
     qg = q.reshape(S, W, KV, G, hd)
     scores = jnp.einsum("swkgd,stkd->skgwt", qg, gk).astype(jnp.float32) \
         * scale

@@ -1,14 +1,13 @@
 """Pallas TPU kernel: fused RMSNorm over the last axis.
 
 Tiling: rows are flattened to (R, D); the grid walks row blocks. Each step
-holds a (block_rows, D) tile of x plus the (D,) scale in VMEM, computes the
-fp32 row-wise rsqrt(mean-square) and writes the scaled tile. D is kept whole
-per tile (lane-dim multiple of 128 for the VPU); block_rows is chosen so the
-working set stays ≪ VMEM (~16 MB on v5e):
-
-    bytes ≈ block_rows · D · (2 in + 2 out) + 4·D  → block_rows = 256 at
-    D = 16384 is ~16 MB? no: 256·16384·4 = 16 MB — we cap block_rows so the
-    tile stays under ~4 MB and let the grid scale instead.
+holds a (block_rows, D) tile of x plus the (1, D) scale in VMEM, computes
+the fp32 row-wise rsqrt(mean-square) and writes the scaled tile. D is kept
+whole per tile. ``block_rows`` is a multiple of 8 (the sublane tile) and
+the tile stays under ~4 MB of VMEM; the row count is zero-padded up to a
+whole number of blocks and the padding sliced off the result, so any row
+count (a 13-token prompt, a decode batch of 12) compiles. Zero rows
+normalize to zeros, never NaN (``rsqrt(0 + eps)``).
 """
 from __future__ import annotations
 
@@ -17,6 +16,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+SUBLANE = 8
 
 
 def _rmsnorm_kernel(x_ref, scale_ref, o_ref, *, eps: float):
@@ -27,34 +28,35 @@ def _rmsnorm_kernel(x_ref, scale_ref, o_ref, *, eps: float):
 
 
 def pick_block_rows(n_rows: int, d: int, budget_bytes: int = 4 << 20) -> int:
-    per_row = d * 8  # fp32 in-tile + output
-    block = max(1, min(n_rows, budget_bytes // per_row))
-    # favor multiples of 8 (sublane) when possible
-    if block >= 8:
-        block -= block % 8
-    while n_rows % block:
-        block -= 1
-    return max(block, 1)
+    """Rows per block: the fewest blocks whose tiles (fp32 in + out, ``8 *
+    d`` bytes a row) fit the budget, each a multiple of the sublane tile."""
+    cap = max(SUBLANE, budget_bytes // (d * 8) // SUBLANE * SUBLANE)
+    n_blocks = -(-n_rows // cap)
+    per_block = -(-n_rows // n_blocks)
+    return -(-per_block // SUBLANE) * SUBLANE
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "interpret", "block_rows"))
+@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
 def rmsnorm(x: jax.Array, scale: jax.Array, *, eps: float = 1e-5,
-            block_rows: int = 0, interpret: bool = False) -> jax.Array:
+            interpret: bool = False) -> jax.Array:
     orig_shape = x.shape
     d = x.shape[-1]
     xf = x.reshape(-1, d)
     rows = xf.shape[0]
-    br = block_rows or pick_block_rows(rows, d)
-    grid = (rows // br,)
+    br = pick_block_rows(rows, d)
+    pad = -rows % br
+    if pad:
+        xf = jnp.pad(xf, ((0, pad), (0, 0)))
     out = pl.pallas_call(
         functools.partial(_rmsnorm_kernel, eps=eps),
-        grid=grid,
+        grid=((rows + pad) // br,),
         in_specs=[
             pl.BlockSpec((br, d), lambda i: (i, 0)),
-            pl.BlockSpec((d,), lambda i: (0,)),
+            pl.BlockSpec((1, d), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((rows + pad, d), x.dtype),
         interpret=interpret,
-    )(xf, scale)
-    return out.reshape(orig_shape)
+        name="rmsnorm",
+    )(xf, scale.reshape(1, d))
+    return out[:rows].reshape(orig_shape)

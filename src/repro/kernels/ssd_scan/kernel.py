@@ -8,9 +8,13 @@ running state lives in fp32 VMEM scratch and is carried across the
 *innermost, sequential* chunk axis of the grid, so state passing costs no
 HBM traffic.
 
-Grid: (B, H, T/Q) with chunk innermost. Per step the kernel holds
-x (Q,P), dt (Q,1), B/C (Q,N), scores (Q,Q) and state (P,N) in VMEM —
-≈ 1 MB fp32 at Q=256, P=64, N=128.
+Grid: (B, H, T/Q) with chunk innermost. The per-head scalars ``A`` and
+``D`` sit whole in SMEM and are read at the head's grid index. ``dt``
+arrives twice, as a (Q, 1) column and a (1, Q) row, so the kernel needs
+no transpose; the inclusive cumulative sum of ``dt·A`` is a masked
+reduction of the (Q, Q) triangle in both orientations (Mosaic has no
+cumsum). Per step the kernel holds x (Q,P), B/C (Q,N), scores (Q,Q) and
+state (P,N) in VMEM — ≈ 1 MB fp32 at Q=256, P=64, N=128.
 """
 from __future__ import annotations
 
@@ -22,8 +26,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, state_scr,
-                *, chunk: int):
+def _ssd_kernel(x_ref, dtc_ref, dtr_ref, a_ref, b_ref, c_ref, d_ref, y_ref,
+                state_scr, *, chunk: int):
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -32,32 +36,36 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, state_scr,
 
     h = pl.program_id(1)
     x = x_ref[0, 0].astype(jnp.float32)        # (Q, P)
-    dt = dt_ref[0, 0].astype(jnp.float32)      # (Q, 1)
+    dt_c = dtc_ref[0, 0].astype(jnp.float32)   # (Q, 1)
+    dt_r = dtr_ref[0, 0, 0].astype(jnp.float32)  # (1, Q)
     bm = b_ref[0, 0].astype(jnp.float32)       # (Q, N)
     cm = c_ref[0, 0].astype(jnp.float32)       # (Q, N)
-    a = a_ref[0]                               # scalar (per head)
-    d = d_ref[0]
+    a = a_ref[h]                               # scalar (per head), SMEM
+    d = d_ref[h]
 
-    dA = dt[:, 0] * a                          # (Q,)
-    cum = jnp.cumsum(dA)                       # inclusive
-    total = cum[-1]
+    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    lower = ii >= jj
+    # inclusive cumsum of dA = dt·A as a column (over j <= i) and a row
+    # (over i <= j)
+    cum_c = jnp.sum(jnp.where(lower, dt_r * a, 0.0), axis=1, keepdims=True)
+    cum_r = jnp.sum(jnp.where(ii <= jj, dt_c * a, 0.0), axis=0,
+                    keepdims=True)
+    total = jnp.sum(dt_c * a, axis=0, keepdims=True)             # (1, 1)
 
     # intra-chunk: scores[i,j] = C_i·B_j · exp(cum_i - cum_j) · dt_j, i ≥ j
     cb = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())))   # (Q, Q)
-    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    ldiff = cum[:, None] - cum[None, :]
-    l_mat = jnp.where(ii >= jj, jnp.exp(ldiff), 0.0)
-    scores = cb * l_mat * dt[:, 0][None, :]
-    y = jax.lax.dot_general(scores, x, (((1,), (0,)), ((), ())))  # (Q, P)
+    l_mat = jnp.where(lower, jnp.exp(cum_c - cum_r), 0.0)
+    scores = cb * l_mat * dt_r
+    y = jnp.dot(scores, x)                                       # (Q, P)
 
     # inter-chunk: y += exp(cum_i) · C_i · state_inᵀ
     state_in = state_scr[...]                  # (P, N)
-    y += jnp.exp(cum)[:, None] * jax.lax.dot_general(
+    y += jnp.exp(cum_c) * jax.lax.dot_general(
         cm, state_in, (((1,), (1,)), ((), ())))
 
     # state update: state' = exp(total)·state + Σ_j dt_j e^{total-cum_j} x_jᵀB_j
-    w = (dt[:, 0] * jnp.exp(total - cum))[:, None]               # (Q, 1)
+    w = dt_c * jnp.exp(total - cum_c)                            # (Q, 1)
     state_scr[...] = state_in * jnp.exp(total) + jax.lax.dot_general(
         x * w, bm, (((0,), (0,)), ((), ())))                     # (P, N)
 
@@ -75,7 +83,9 @@ def ssd_scan(x, dt, A, Bm, Cm, D, *, chunk: int = 128,
     nC = T // chunk
 
     xt = x.transpose(0, 2, 1, 3)                       # (B, H, T, P)
-    dtt = dt.transpose(0, 2, 1)[..., None]             # (B, H, T, 1)
+    dth = dt.transpose(0, 2, 1)                        # (B, H, T)
+    dtc = dth[..., None]                               # (B, H, T, 1)
+    dtr = dth.reshape(B_, H, nC, 1, chunk)             # (B, H, nC, 1, Q)
     bt = Bm.transpose(0, 2, 1, 3)                      # (B, G, T, N)
     ct = Cm.transpose(0, 2, 1, 3)
 
@@ -86,16 +96,19 @@ def ssd_scan(x, dt, A, Bm, Cm, D, *, chunk: int = 128,
         in_specs=[
             pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, chunk, 1), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
+            pl.BlockSpec((1, 1, 1, 1, chunk),
+                         lambda b, h, c: (b, h, c, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, chunk, N),
                          lambda b, h, c, g=hpg: (b, h // g, c, 0)),
             pl.BlockSpec((1, 1, chunk, N),
                          lambda b, h, c, g=hpg: (b, h // g, c, 0)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
         out_shape=jax.ShapeDtypeStruct((B_, H, T, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
-    )(xt, dtt, A.astype(jnp.float32), bt, ct, D.astype(jnp.float32))
+        name="ssd_scan",
+    )(xt, dtc, dtr, A.astype(jnp.float32), bt, ct, D.astype(jnp.float32))
     return y.transpose(0, 2, 1, 3)

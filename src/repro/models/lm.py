@@ -376,7 +376,7 @@ def lm_paged_decode(params, tokens: jax.Array, cfg: ModelConfig,
 
     ``tokens (S, W)`` int32 — each slot's query window (W=1 decode,
     W=1+K verify, W=padded tail for suffix prefill); ``pool`` is the
-    layer-stacked page pool ``{"k","v": (L, P+1, ps, KVp, hd)}``;
+    layer-stacked page pool ``{"k","v": (L, P+1, KVp, ps, hd)}``;
     ``positions``/``n_valid``: (S,) int32; ``tables``: (S, T) int32.
 
     Returns ``(logits (S, W, vocab), new_pool)``. Layers run under

@@ -26,7 +26,9 @@ cache and position:
   (``Request.deliver``): attached ``TokenStream``s (``serve.api``) wake
   per token with no polling thread, stop sequences match as tokens land,
   and deadline-expired slots are retired by the very continuation that
-  releases their pages. QoS: admission pops strictly by
+  releases their pages. These continuations commit in dispatch order
+  (``_after_device``), whatever order their arrays are discovered
+  complete in. QoS: admission pops strictly by
   ``GenerationConfig.priority`` (the ``Batcher`` heap) and steps carrying
   priority work jump the scheduler's ready queue via the
   per-registration ``priority`` flag.
@@ -109,6 +111,15 @@ _PRIO_FLAGS: dict = {}
 _PRIO_FLAGS_MAX = 64
 
 
+def _home_device(params: Any) -> jax.Device:
+    """The one device holding ``params``."""
+    devices = jax.tree_util.tree_leaves(params)[0].devices()
+    if len(devices) != 1:
+        raise ValueError(f"ServeEngine params must sit on one device, got "
+                         f"{sorted(map(str, devices))}")
+    return next(iter(devices))
+
+
 def _step_flags(priority: int) -> ContinueFlags:
     if priority <= 0:
         return _STEP_FLAGS
@@ -173,6 +184,9 @@ class ServeEngine:
             raise ValueError("fused paged-attention steps require paged=True")
         self.cfg = cfg
         self.params = params
+        # every array the engine makes lives beside its params (a router
+        # replica's params sit on that replica's chip)
+        self.device = _home_device(params)
         self.max_batch = int(max_batch)
         self.max_cache_len = int(max_cache_len)
         self.max_inflight = max(1, int(max_inflight))
@@ -215,7 +229,8 @@ class ServeEngine:
             self._padded_len = self._table_pages * self.page_size
             n_pool = int(total_pages) if total_pages is not None \
                 else S * self.max_pages
-            self.pool = PagePool(cfg, n_pool, self.page_size)
+            self.pool = PagePool(cfg, n_pool, self.page_size,
+                                 device=self.device)
             self._tables = np.full((S, self._table_pages),
                                    self.pool.null_page, np.int32)
             # device-resident mirror of _tables, refreshed incrementally:
@@ -271,6 +286,12 @@ class ServeEngine:
         # actually complete — what the drafter matches against
         self._ctx: List[Optional[List[int]]] = [None] * S
         self._inflight = 0                    # dispatched, not-yet-complete steps
+        # in-order commit of device-work continuations (``_after_device``):
+        # sequence numbers handed out at dispatch / next one to commit, and
+        # continuations that ran ahead of an earlier one, parked by number
+        self._dispatched = 0
+        self._committed = 0
+        self._parked: dict = {}
         self._stalled_at: Optional[int] = None  # pages_in_use at last deferral
         self._retired: List[Request] = []
         self._lock = threading.Lock()         # guards _retired for readers
@@ -327,15 +348,52 @@ class ServeEngine:
             return list(self._retired)
 
     # ---------------------------------------------------------- slot state
+    def _put(self, host: Any) -> jax.Array:
+        """Upload host state to the engine's device as a private copy.
+
+        The engine mutates ``_pos`` and ``_tables`` right after
+        dispatching a step that reads them. On the CPU backend
+        ``jnp.asarray``/``jax.device_put`` may alias an aligned NumPy
+        buffer, and ``jnp.array`` may read it after returning, so the step
+        could see a later step's state. A NumPy copy that nothing else
+        holds cannot change under it."""
+        return jax.device_put(np.array(host), self.device)
+
+    def _after_device(self, out: Any, fn, meta: Any, priority: int) -> None:
+        """Run ``fn(meta)`` once ``out`` is complete, in dispatch order.
+
+        Every prefill, decode and verify step reads what the steps before
+        it wrote, yet a poll scan can find a later step's array ready
+        first (it polled the earlier one just before that completed), and
+        the scheduler runs higher-priority continuations first. Tokens
+        must be delivered and slots retired in dispatch order, so a
+        continuation that runs ahead of an earlier one is parked until
+        that one has committed."""
+        seq = self._dispatched
+        self._dispatched += 1
+        self.engine.continue_when(ArrayOp(out), self._commit,
+                                  (seq, fn, meta), cr=self.cr_steps,
+                                  flags=_step_flags(priority))
+
+    def _commit(self, statuses, data) -> None:
+        seq, fn, meta = data
+        self._parked[seq] = (fn, meta)
+        while self._committed in self._parked:
+            fn, meta = self._parked.pop(self._committed)
+            self._committed += 1
+            fn(meta)
+
     def _ensure_state(self) -> None:
         if self._tokens is None:
-            self._tokens = jnp.zeros((self.max_batch, 1, 1), jnp.int32)
+            self._tokens = jnp.zeros((self.max_batch, 1, 1), jnp.int32,
+                                     device=self.device)
         if self.paged:
             self.pool.ensure_arrays()
         elif self._cache is None:
             base = lm.init_cache(self.cfg, 1, self.max_cache_len)
-            self._cache = jax.tree_util.tree_map(
-                lambda x: jnp.stack([x] * self.max_batch), base)
+            self._cache = jax.device_put(jax.tree_util.tree_map(
+                lambda x: jnp.stack([x] * self.max_batch), base),
+                self.device)
 
     def _free_slots(self) -> List[int]:
         return [i for i, r in enumerate(self._slots) if r is None]
@@ -373,7 +431,7 @@ class ServeEngine:
     def _place(self, req: Request, free: List[int]) -> bool:
         """Prefill ``req`` and seat it in a slot. False = defer (paged
         capacity); True = placed (or answered outright by prefill)."""
-        prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
+        prompt = self._put(np.asarray(req.prompt, np.int32).reshape(1, -1))
         plen = prompt.shape[1]
         tr = _obs.TRACE
         t0 = None
@@ -390,10 +448,8 @@ class ServeEngine:
             first = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
             req.push_device_token(first[0])
             self.stats["prefills"] += 1
-            self.engine.continue_when(ArrayOp(first), self._on_prefill_done,
-                                      (req, True, None, first, t0),
-                                      cr=self.cr_steps,
-                                      flags=_step_flags(req.priority))
+            self._after_device(first, self._on_prefill_done,
+                               (req, True, None, first, t0), req.priority)
             return True
 
         self._ensure_state()
@@ -420,10 +476,8 @@ class ServeEngine:
             ctx = [int(t) for t in
                    np.asarray(req.prompt, np.int32).reshape(-1)]
         self._seat_slot(slot, req, first[:, None], plen, ctx=ctx)
-        self.engine.continue_when(ArrayOp(first), self._on_prefill_done,
-                                  (req, False, slot, first, t0),
-                                  cr=self.cr_steps,
-                                  flags=_step_flags(req.priority))
+        self._after_device(first, self._on_prefill_done,
+                           (req, False, slot, first, t0), req.priority)
         return True
 
     def _seat_slot(self, slot: int, req: Request, token0: Any, plen: int,
@@ -498,13 +552,12 @@ class ServeEngine:
                 # padding rows are untouched (n_valid masks the pad)
                 logits, pool.arrays = self._suffix_fn(
                     self.params, pool.arrays, suffix,
-                    jnp.asarray([start], jnp.int32),
-                    self._padded_table(table)[None],
-                    jnp.asarray([tail], jnp.int32))
+                    self._put([start]), self._padded_table(table)[None],
+                    self._put([tail]))
             else:
                 logits, pool.arrays = self._suffix_fn(
                     self.params, pool.arrays, suffix, jnp.int32(start),
-                    self._padded_table(table), jnp.asarray(scat))
+                    self._padded_table(table), self._put(scat))
             self.stats["suffix_steps"] += 1
             self.stats["suffix_tokens"] += tail
             first = jnp.argmax(logits[:, tail - 1], axis=-1).astype(jnp.int32)
@@ -518,14 +571,14 @@ class ServeEngine:
             n_prompt_pages = pages_for(plen, ps)
             scatter_table[:n_prompt_pages] = table[:n_prompt_pages]
             pool.arrays = self._scatter_fn(pool.arrays, cache1,
-                                           jnp.asarray(scatter_table))
+                                           self._put(scatter_table))
         pool.register_prefix(req.prompt, table)
         return first
 
     def _padded_table(self, table: Sequence[int]) -> jax.Array:
         out = np.full(self._table_pages, self.pool.null_page, np.int32)
         out[:len(table)] = table
-        return jnp.asarray(out)
+        return self._put(out)
 
     def _device_tables(self) -> jax.Array:
         """Device copy of the page tables, updated incrementally.
@@ -538,17 +591,17 @@ class ServeEngine:
         decode (no placements) re-uses the resident array with zero
         transfer."""
         if self._tables_dev is None:
-            self._tables_dev = jnp.asarray(self._tables)
+            self._tables_dev = self._put(self._tables)
             self._tables_dirty.clear()
         elif self._tables_dirty:
             rows = sorted(self._tables_dirty)
             self._tables_dev = self._tables_dev.at[
-                jnp.asarray(rows, jnp.int32)].set(
-                jnp.asarray(self._tables[rows]))
+                self._put(np.asarray(rows, np.int32))].set(
+                self._put(self._tables[rows]))
             self._tables_dirty.clear()
         return self._tables_dev
 
-    def _on_prefill_done(self, statuses, meta) -> None:
+    def _on_prefill_done(self, meta) -> None:
         req, retire_now, slot, first, t0 = meta
         if t0 is not None:
             tr = _obs.TRACE
@@ -611,16 +664,15 @@ class ServeEngine:
             nv[[i for i, _ in live]] = 1
             nxt, self.pool.arrays = self._decode_fn(
                 self.params, self.pool.arrays, self._tokens,
-                jnp.asarray(self._pos), self._device_tables(),
-                jnp.asarray(nv))
+                self._put(self._pos), self._device_tables(), self._put(nv))
         elif self.paged:
             nxt, self.pool.arrays = self._decode_fn(
                 self.params, self.pool.arrays, self._tokens,
-                jnp.asarray(self._pos), self._device_tables())
+                self._put(self._pos), self._device_tables())
         else:
             nxt, self._cache = self._decode_fn(
                 self.params, self._cache, self._tokens,
-                jnp.asarray(self._pos))
+                self._put(self._pos))
         # the jitted step surfaces per-slot next tokens directly: (S, 1)
         self._tokens = nxt[..., None]                       # (S, 1, 1)
         stepped: List[Tuple[int, Request, bool]] = []
@@ -640,12 +692,10 @@ class ServeEngine:
         self.stats["max_active"] = max(self.stats["max_active"], len(live))
         tr = _obs.TRACE
         t0 = tr.now() if tr is not None else None
-        self.engine.continue_when(ArrayOp(nxt), self._on_step_done,
-                                  (stepped, nxt, t0), cr=self.cr_steps,
-                                  flags=_step_flags(prio))
+        self._after_device(nxt, self._on_step_done, (stepped, nxt, t0), prio)
         return True
 
-    def _on_step_done(self, statuses, meta) -> None:
+    def _on_step_done(self, meta) -> None:
         """Per-token bookkeeping when the step's device work is actually
         complete: deliver each slot's token (streams wake here), then
         retire slots that finished — by budget, by a stop-sequence match,
@@ -726,7 +776,7 @@ class ServeEngine:
             n_drafts[i] = len(d)
             drafts[i, :len(d)] = d
         tokens = jnp.concatenate(
-            [self._tokens, jnp.asarray(drafts)[:, None, :]], axis=2)
+            [self._tokens, self._put(drafts)[:, None, :]], axis=2)
         if self.fused:
             # no host-built write tables at all: the kernel accept-masks
             # the window to n_valid = 1 + live drafts (0 for idle /
@@ -737,8 +787,7 @@ class ServeEngine:
                 nv[i] = 1 + n_drafts[i]
             emitted, accepts, self.pool.arrays = self._verify_fn(
                 self.params, self.pool.arrays, tokens,
-                jnp.asarray(self._pos), self._device_tables(),
-                jnp.asarray(nv))
+                self._put(self._pos), self._device_tables(), self._put(nv))
         else:
             # write tables: rows for idle / still-verifying slots stay all
             # null, so their (garbage) lanes scatter into the scratch page
@@ -750,8 +799,8 @@ class ServeEngine:
                                                    self._verify_pages)
             emitted, accepts, self.pool.arrays = self._verify_fn(
                 self.params, self.pool.arrays, tokens,
-                jnp.asarray(self._pos), self._device_tables(),
-                jnp.asarray(wtables), jnp.asarray(n_drafts))
+                self._put(self._pos), self._device_tables(),
+                self._put(wtables), self._put(n_drafts))
         self._verifying.update(i for i, _ in live)
         self._inflight += 1
         self.stats["steps"] += 1
@@ -762,14 +811,12 @@ class ServeEngine:
         self.stats["max_active"] = max(self.stats["max_active"], len(live))
         tr = _obs.TRACE
         t0 = tr.now() if tr is not None else None
-        self.engine.continue_when(ArrayOp(emitted), self._on_verify_done,
-                                  (live, emitted, accepts, n_drafts, t0),
-                                  cr=self.cr_steps,
-                                  flags=_step_flags(
-                                      max(r.priority for _, r in live)))
+        self._after_device(emitted, self._on_verify_done,
+                           (live, emitted, accepts, n_drafts, t0),
+                           max(r.priority for _, r in live))
         return True
 
-    def _on_verify_done(self, statuses, meta) -> None:
+    def _on_verify_done(self, meta) -> None:
         """Accept bookkeeping — runs when the verify step's arrays are
         actually complete, so the host reads below never block. Mixed
         accept lengths advance each slot independently; a slot whose
@@ -841,8 +888,8 @@ class ServeEngine:
             mask[upd_slots] = True
             vals[upd_slots] = upd_tokens
             self._tokens = jnp.where(
-                jnp.asarray(mask)[:, None, None],
-                jnp.asarray(vals)[:, None, None], self._tokens)
+                self._put(mask)[:, None, None],
+                self._put(vals)[:, None, None], self._tokens)
 
     def _finish_slot(self, slot: Optional[int], req: Request,
                      kind: str) -> None:

@@ -7,8 +7,9 @@ paper's §5.3 burst handling removes from the *scheduling* side). The
 ``PagePool`` removes it from the *memory* side:
 
 * **block pool** — one device allocation of ``total_pages`` fixed-size
-  pages per layer (``k``/``v``: ``(n_layers, total_pages + 1, page_size,
-  kv_heads, head_dim)``); a request only holds pages proportional to its
+  pages per layer (``k``/``v``: ``(n_layers, total_pages + 1, kv_heads,
+  page_size, head_dim)`` — the layout contract of
+  ``kernels.paged_attention``); a request only holds pages proportional to its
   sequence, so the pool oversubscribes slots the way rtp-llm's block
   cache manager does. Index ``total_pages`` is a scratch ("null") page:
   page-table padding points at it, so idle slots and table tails
@@ -73,8 +74,8 @@ def prefix_keys(tokens: Any, page_size: int,
 
 @jax.jit
 def _page_slice(arr: jax.Array, page: jax.Array) -> jax.Array:
-    """One page's KV out of a pool array: (L, P+1, ps, KV, hd) →
-    (L, ps, KV, hd). ``page`` is traced, so every page id shares one
+    """One page's KV out of a pool array: (L, P+1, KV, ps, hd) →
+    (L, KV, ps, hd). ``page`` is traced, so every page id shares one
     compilation."""
     return jax.lax.dynamic_index_in_dim(arr, page, axis=1, keepdims=False)
 
@@ -111,7 +112,7 @@ class PagePool:
     """
 
     def __init__(self, cfg: ModelConfig, total_pages: int,
-                 page_size: int) -> None:
+                 page_size: int, device: Optional[jax.Device] = None) -> None:
         if not paged_supported(cfg):
             raise ValueError(
                 f"paged KV cache unsupported for family={cfg.family!r} "
@@ -119,6 +120,9 @@ class PagePool:
         self.cfg = cfg
         self.total_pages = int(total_pages)
         self.page_size = int(page_size)
+        # the device the pool arrays live on: the owning engine's (None =
+        # JAX's default placement)
+        self.device = device
         self.null_page = self.total_pages      # scratch page, never owned
         self._free: List[int] = list(range(self.total_pages))
         self._ref = np.zeros(self.total_pages, np.int32)
@@ -136,10 +140,10 @@ class PagePool:
         if self.arrays is not None:
             return
         cfg = self.cfg
-        shape = (cfg.n_layers, self.total_pages + 1, self.page_size,
-                 cfg.padded_kv_heads, cfg.resolved_head_dim)
-        self.arrays = {"k": jnp.zeros(shape, cfg.dtype),
-                       "v": jnp.zeros(shape, cfg.dtype)}
+        shape = (cfg.n_layers, self.total_pages + 1, cfg.padded_kv_heads,
+                 self.page_size, cfg.resolved_head_dim)
+        self.arrays = {"k": jnp.zeros(shape, cfg.dtype, device=self.device),
+                       "v": jnp.zeros(shape, cfg.dtype, device=self.device)}
 
     @property
     def page_nbytes(self) -> int:
@@ -152,7 +156,7 @@ class PagePool:
     # ------------------------------------------------- remote page shipping
     def export_page(self, page: int) -> Dict[str, Any]:
         """Copy one resident page out of the pool: ``{"k", "v"}`` device
-        arrays of shape ``(n_layers, page_size, kv_heads, head_dim)``.
+        arrays of shape ``(n_layers, kv_heads, page_size, head_dim)``.
 
         The slices are fresh buffers ordered after every write already
         dispatched against the pool (jax data dependency), so a prefill
@@ -171,8 +175,8 @@ class PagePool:
         self.ensure_arrays()
         page_idx = jnp.int32(page)
         for k in self.arrays:
-            self.arrays[k] = _page_install(self.arrays[k], page_idx,
-                                           data[k])
+            self.arrays[k] = _page_install(
+                self.arrays[k], page_idx, jax.device_put(data[k], self.device))
         self.stats["pages_imported"] += 1
 
     # ---------------------------------------------------------- free list

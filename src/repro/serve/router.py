@@ -60,6 +60,7 @@ from dataclasses import dataclass
 from typing import (Any, Callable, Dict, FrozenSet, List, Optional, Set,
                     Union)
 
+import jax
 import numpy as np
 
 from repro.core import ContinueFlags, Engine, OpState, Scheduler, Transport
@@ -273,7 +274,9 @@ class Router:
     Construction: either pass ``replicas=[tier, ...]`` (pre-built
     ``EngineLike`` tiers sharing ``engine=``), or ``(cfg, params)`` with
     ``n_replicas`` and engine kwargs to build homogeneous ``ServeEngine``
-    replicas. Policy knobs:
+    replicas — replica ``i`` gets its own copy of ``params`` on device
+    ``i mod len(jax.devices())``, so on a host with N chips N replicas
+    each own one chip (params, page pool and steps). Policy knobs:
 
     * ``weights`` / ``quantum`` — tenant fairness (``FairBatcher``).
     * ``quota`` — max outstanding requests per tenant (int for all, or
@@ -303,9 +306,11 @@ class Router:
         if replicas is None:
             if cfg is None or params is None:
                 raise ValueError("Router needs (cfg, params) or replicas=")
-            replicas = [ServeEngine(cfg, params, engine=self.engine,
-                                    **engine_kwargs)
-                        for _ in range(int(n_replicas))]
+            devices = jax.devices()
+            replicas = [ServeEngine(cfg, jax.device_put(
+                                        params, devices[i % len(devices)]),
+                                    engine=self.engine, **engine_kwargs)
+                        for i in range(int(n_replicas))]
         elif engine_kwargs:
             raise ValueError("replicas= and engine kwargs are exclusive")
         if not replicas:

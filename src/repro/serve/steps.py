@@ -82,17 +82,24 @@ def make_batched_decode_step(cfg: ModelConfig) -> Callable:
 
 # ------------------------------------------------------------- paged steps
 
+def _pool_pages(c: jax.Array, page_size: int) -> jax.Array:
+    """Contiguous cache rows -> pool pages: (L, n*ps, KV, hd) ->
+    (L, n, KV, ps, hd), the pool's page-major, kv-head-major layout."""
+    L = c.shape[0]
+    return c.reshape((L, -1, page_size) + c.shape[2:]).swapaxes(2, 3)
+
+
 def _gather_pages(pool: Dict[str, jax.Array], table: jax.Array,
                   page_size: int) -> Dict[str, jax.Array]:
     """Page table -> contiguous per-slot cache view.
 
-    pool leaf: (L, total_pages+1, page_size, KV, hd); table: (max_pages,)
+    pool leaf: (L, total_pages+1, KV, page_size, hd); table: (max_pages,)
     -> (L, 1, max_pages*page_size, KV, hd), i.e. a batch-1 stacked cache
     exactly as ``lm_decode_step`` expects. Null-padded table tails gather
     scratch-page garbage, which the decode mask (idx <= pos) zeroes out.
     """
     def one(p):
-        g = p[:, table]                       # (L, max_pages, ps, KV, hd)
+        g = p[:, table].swapaxes(2, 3)        # (L, max_pages, ps, KV, hd)
         L, n_pages = g.shape[0], g.shape[1]
         return g.reshape((L, n_pages * page_size) + g.shape[3:])[:, None]
     return jax.tree_util.tree_map(one, pool)
@@ -100,11 +107,13 @@ def _gather_pages(pool: Dict[str, jax.Array], table: jax.Array,
 
 def _written_page(new_cache: Dict[str, jax.Array], pos: jax.Array,
                   page_size: int) -> Dict[str, jax.Array]:
-    """Slice the page containing ``pos`` out of the contiguous view."""
+    """Slice the page containing ``pos`` out of the contiguous view, in
+    pool layout (L, KV, ps, hd)."""
     pi = (pos // page_size).astype(jnp.int32)
     return jax.tree_util.tree_map(
-        lambda c: jax.lax.dynamic_slice_in_dim(
-            c[:, 0], pi * page_size, page_size, axis=1), new_cache)
+        lambda c: _pool_pages(jax.lax.dynamic_slice_in_dim(
+            c[:, 0], pi * page_size, page_size, axis=1), page_size)[:, 0],
+        new_cache)
 
 
 def make_paged_decode_step(cfg: ModelConfig, page_size: int, *,
@@ -183,10 +192,9 @@ def make_paged_verify_step(cfg: ModelConfig, page_size: int,
             accept = jnp.sum(jnp.cumprod(ok.astype(jnp.int32)))
             pi = (pos // page_size).astype(jnp.int32)
             pages = jax.tree_util.tree_map(
-                lambda c: jax.lax.dynamic_slice_in_dim(
-                    c[:, 0], pi * page_size, n_wpages * page_size, axis=1
-                ).reshape((c.shape[0], n_wpages, page_size) + c.shape[3:]),
-                new_cache)
+                lambda c: _pool_pages(jax.lax.dynamic_slice_in_dim(
+                    c[:, 0], pi * page_size, n_wpages * page_size, axis=1),
+                    page_size), new_cache)
             return emitted, accept.astype(jnp.int32), pages
 
         emitted, accepts, pages = jax.vmap(one)(tokens, positions, tables,
@@ -195,8 +203,8 @@ def make_paged_verify_step(cfg: ModelConfig, page_size: int,
         # write pages are request-owned and disjoint across slots, so the
         # flattened scatter collides only on the scratch page (idle slots,
         # out-of-footprint tails) where order is irrelevant
-        def scat(p, pg):                      # pg: (S, L, W, ps, KV, hd)
-            pg = jnp.moveaxis(pg, 0, 1)       # (L, S, W, ps, KV, hd)
+        def scat(p, pg):                      # pg: (S, L, W, KV, ps, hd)
+            pg = jnp.moveaxis(pg, 0, 1)       # (L, S, W, KV, ps, hd)
             pg = pg.reshape((pg.shape[0], -1) + pg.shape[3:])
             return p.at[:, write_tables.reshape(-1)].set(pg)
 
@@ -220,9 +228,7 @@ def make_paged_suffix_step(cfg: ModelConfig, page_size: int) -> Callable:
         logits, new_cache = decode_one(params, cache, tokens, pos)
 
         def one(p, c):
-            L = c.shape[0]
-            pages = c[:, 0].reshape((L, -1, page_size) + c.shape[3:])
-            return p.at[:, scatter_table].set(pages)
+            return p.at[:, scatter_table].set(_pool_pages(c[:, 0], page_size))
         return logits, jax.tree_util.tree_map(one, pool, new_cache)
     return step
 
@@ -303,10 +309,7 @@ def make_prefill_scatter(cfg: ModelConfig, page_size: int) -> Callable:
     page receiving tokens [i*ps, (i+1)*ps) — null past the prompt."""
     def scatter(pool, dense_cache, table):
         def one(p, c):
-            L = c.shape[0]
-            pages = c[:, 0].reshape(
-                (L, -1, page_size) + c.shape[3:])   # (L, max_pages, ps, ..)
-            return p.at[:, table].set(pages)
+            return p.at[:, table].set(_pool_pages(c[:, 0], page_size))
         return jax.tree_util.tree_map(one, pool, dense_cache)
     return scatter
 

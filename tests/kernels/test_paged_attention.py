@@ -36,9 +36,9 @@ def _scenario(seed, S, W, H, KV, hd, ps, T, n_real, positions, n_valid,
         q=jnp.asarray(rng.normal(size=(S, W, H, hd)), jnp.float32),
         k_new=jnp.asarray(rng.normal(size=(S, W, KV, hd)), jnp.float32),
         v_new=jnp.asarray(rng.normal(size=(S, W, KV, hd)), jnp.float32),
-        k_pages=jnp.asarray(rng.normal(size=(n_real + 1, ps, KV, hd)),
+        k_pages=jnp.asarray(rng.normal(size=(n_real + 1, KV, ps, hd)),
                             jnp.float32),
-        v_pages=jnp.asarray(rng.normal(size=(n_real + 1, ps, KV, hd)),
+        v_pages=jnp.asarray(rng.normal(size=(n_real + 1, KV, ps, hd)),
                             jnp.float32),
         tables=jnp.asarray(tables),
         positions=jnp.asarray(positions, jnp.int32),
@@ -48,9 +48,10 @@ def _scenario(seed, S, W, H, KV, hd, ps, T, n_real, positions, n_valid,
 
 
 def _compare(args, null):
-    o_r, k_r, v_r = pa_ref.paged_attention(**args, page_size=args["k_pages"].shape[1])
+    ps = args["k_pages"].shape[2]
+    o_r, k_r, v_r = pa_ref.paged_attention(**args, page_size=ps)
     o_k, k_k, v_k = pa_kernel.paged_attention(
-        **args, page_size=args["k_pages"].shape[1], interpret=True)
+        **args, page_size=ps, interpret=True)
     np.testing.assert_allclose(np.asarray(o_r), np.asarray(o_k), **TOL)
     # pools bit-exact on every REAL page (the scratch page is garbage by
     # contract: the ref parks rejected rows there, the kernel does not)
@@ -114,9 +115,9 @@ def test_accept_masked_rows_only_touch_scratch():
         p = tables[0, (pos + j) // ps]
         row = (pos + j) % ps
         if j < nv:
-            np.testing.assert_array_equal(k_k[p, row], kn[0, j])
+            np.testing.assert_array_equal(k_k[p, :, row], kn[0, j])
         else:
-            np.testing.assert_array_equal(k_k[p, row], kp[p, row])
+            np.testing.assert_array_equal(k_k[p, :, row], kp[p, :, row])
 
 
 def test_shared_page_read_only():
@@ -141,16 +142,16 @@ def test_ref_matches_dense_oracle():
     tables = np.full((S, T), null, np.int32)
     tables[0, :3] = [0, 1, 2]
     tables[1, :3] = [3, 4, 5]
-    kp = np.zeros((n_real + 1, ps, KV, hd), np.float32)
-    vp = np.zeros((n_real + 1, ps, KV, hd), np.float32)
+    kp = np.zeros((n_real + 1, KV, ps, hd), np.float32)
+    vp = np.zeros((n_real + 1, KV, ps, hd), np.float32)
     hist_k = [rng.normal(size=(hl, KV, hd)).astype(np.float32)
               for hl in hist_len]
     hist_v = [rng.normal(size=(hl, KV, hd)).astype(np.float32)
               for hl in hist_len]
     for s in range(S):
         for t in range(hist_len[s]):
-            kp[tables[s, t // ps], t % ps] = hist_k[s][t]
-            vp[tables[s, t // ps], t % ps] = hist_v[s][t]
+            kp[tables[s, t // ps], :, t % ps] = hist_k[s][t]
+            vp[tables[s, t // ps], :, t % ps] = hist_v[s][t]
     q = rng.normal(size=(S, W, H, hd)).astype(np.float32)
     kn = rng.normal(size=(S, W, KV, hd)).astype(np.float32)
     vn = rng.normal(size=(S, W, KV, hd)).astype(np.float32)

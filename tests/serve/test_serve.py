@@ -262,3 +262,59 @@ def test_submit_async_cancel_rejects(small_model):
         assert req.req_state is RequestState.CANCELLED
     finally:
         eng.shutdown()
+
+
+def test_put_snapshots_host_state(small_model):
+    """Host state the engine mutates right after dispatching a step
+    (``_pos``, ``_tables``) reaches the device as a private copy: a later
+    mutation never shows through. ``jnp.asarray`` may alias an aligned
+    NumPy buffer on the CPU backend, which made greedy token streams
+    diverge under load."""
+    import numpy as np
+    cfg, params, _ = small_model
+    eng = ServeEngine(cfg, params, max_batch=2, max_cache_len=32)
+    try:
+        for n in range(1, 129):
+            host = np.zeros(n, np.int32)
+            dev = eng._put(host)
+            host += 1
+            assert int(dev.sum()) == 0, n
+            assert dev.devices() == {eng.device}
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("kw", [
+    dict(paged=False),
+    dict(paged=True, page_size=8, max_seq_len=32),
+    dict(paged=True, page_size=8, max_seq_len=32, speculate=2),
+], ids=["dense", "paged", "speculative"])
+def test_tokens_commit_in_dispatch_order(small_model, monkeypatch, kw):
+    """Completion discovery may find a later step's array ready before an
+    earlier one's (a scan polls the earlier op just before it completes).
+    Here every scan waits for all in-flight device work and then discovers
+    ops newest first: the engine must still deliver tokens and retire
+    slots in dispatch order."""
+    from repro.core.completable import ArrayOp
+    from repro.core.progress import Progress
+
+    def newest_first_scan(self):
+        with self._poll_lock:
+            ops = list(self._poll_ops)
+        for op in ops:
+            if isinstance(op, ArrayOp):
+                jax.block_until_ready(op._leaves)
+        done = {id(op) for op in reversed(ops) if op.done()}
+        with self._poll_lock:
+            self._poll_ops = [op for op in self._poll_ops
+                              if id(op) not in done]
+
+    cfg, params, prompts = small_model
+    base = [list(map(int, greedy_generate(cfg, params, prompts[i:i + 1], 5,
+                                          max_cache_len=16)[0]))
+            for i in range(3)]
+    monkeypatch.setattr(Progress, "scan", newest_first_scan)
+    reqs = serve_requests(cfg, params,
+                          [Request(prompts[i], 5) for i in range(3)],
+                          max_batch=2, max_cache_len=16, timeout=300, **kw)
+    assert [r.tokens for r in reqs] == base

@@ -325,8 +325,8 @@ def test_cancel_mid_stream_no_delivery_after_cancel(small_model):
         assert eng._inflight > 0
         assert req.cancel() is True       # …and cancel returns before it
         n_at_cancel = stream.received
-        for _ in range(30):               # run its continuation + sweeps
-            eng.step()
+        # run its continuation + sweeps, however long the device takes
+        _drive_until(eng, lambda: eng.idle)
         assert stream.received == n_at_cancel
         assert list(stream)[:n_at_cancel] == stream._toks
         assert stream.reason == "cancelled"
@@ -352,8 +352,7 @@ def test_cancel_mid_speculative_verify_no_delivery_no_leaks(small_model):
         _drive_until(eng, lambda: eng._dispatch_step() or eng._verifying)
         assert req.cancel() is True
         n_at_cancel = stream.received
-        for _ in range(30):
-            eng.step()
+        _drive_until(eng, lambda: eng.idle)
         assert stream.received == n_at_cancel
         assert stream.reason == "cancelled"
         assert not eng._verifying
@@ -395,15 +394,19 @@ def test_completed_budget_outranks_lapsed_deadline(small_model):
         eng.run(until=lambda: warm.req_state is RequestState.FINISHED,
                 timeout=300)
         req = Request(prompts[0],
-                      GenerationConfig(max_tokens=2, deadline_s=0.2))
+                      GenerationConfig(max_tokens=2, deadline_s=2.0))
         eng.submit(req)
         eng._admit()              # seat + prefill (continuation pending)
-        eng.engine.tick()         # first token delivers before deadline
+        limit = time.monotonic() + 60
+        while req.delivered == 0:  # first token delivers before deadline
+            eng.engine.tick()
+            assert time.monotonic() < limit
+        assert not req.past_deadline()
         eng._dispatch_step()      # final budgeted token now in flight
         assert eng._draining
-        time.sleep(0.3)           # deadline lapses mid-flight
-        for _ in range(30):
-            eng.step()
+        # deadline lapses mid-flight
+        time.sleep(max(0.0, req.deadline_time - time.monotonic()) + 0.05)
+        _drive_until(eng, lambda: eng.idle)
         assert req.req_state is RequestState.FINISHED
         assert len(req.tokens) == 2
         assert eng.stats["expired"] == 0

@@ -1,10 +1,11 @@
-"""Targeted tests for ``repro.compat`` — the JAX version-drift shims.
+"""Targeted tests for ``repro.compat`` — the thin helpers over the
+installed JAX's mesh, shard_map and cost-analysis APIs.
 
-Each shim gets its own test so that the day a new JAX release moves an
+Each helper gets its own test so that the day a new JAX release moves an
 API again, CI reports a named compat failure instead of collateral
-damage across the whole suite. Both branches of every shim are covered:
-the live branch runs against the installed JAX, the other is driven
-through monkeypatched stand-ins."""
+damage across the whole suite."""
+import inspect
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -27,52 +28,25 @@ def test_make_mesh_passes_auto_axis_types_when_supported(monkeypatch):
         return "mesh"
 
     monkeypatch.setattr(jax, "make_mesh", fake_make_mesh)
-    if not hasattr(jax.sharding, "AxisType"):
-        class FakeAxisType:
-            Auto = "auto"
-        monkeypatch.setattr(jax.sharding, "AxisType", FakeAxisType,
-                            raising=False)
     assert compat.make_mesh((1, 1), ("a", "b")) == "mesh"
     assert calls["axis_types"] == (jax.sharding.AxisType.Auto,) * 2
-
-
-def test_make_mesh_omits_axis_types_on_old_jax(monkeypatch):
-    """Pre-AxisType builds reject the kwarg entirely — the shim must not
-    send it."""
-    calls = {}
-
-    def fake_make_mesh(shape, axes, **kwargs):
-        if "axis_types" in kwargs:
-            raise TypeError("unexpected keyword argument 'axis_types'")
-        calls["ok"] = True
-        return "mesh"
-
-    monkeypatch.setattr(jax, "make_mesh", fake_make_mesh)
-    monkeypatch.delattr(jax.sharding, "AxisType", raising=False)
-    assert compat.make_mesh((1,), ("x",)) == "mesh"
-    assert calls["ok"]
 
 
 def test_make_mesh_caller_override_wins(monkeypatch):
     calls = {}
     monkeypatch.setattr(jax, "make_mesh",
                         lambda shape, axes, **kw: calls.update(kw) or "m")
-    if not hasattr(jax.sharding, "AxisType"):
-        class FakeAxisType:
-            Auto, Explicit = "auto", "explicit"
-        monkeypatch.setattr(jax.sharding, "AxisType", FakeAxisType,
-                            raising=False)
-    compat.make_mesh((1,), ("x",), axis_types=("explicit",))
-    assert calls["axis_types"] == ("explicit",)
+    explicit = (jax.sharding.AxisType.Explicit,)
+    compat.make_mesh((1,), ("x",), axis_types=explicit)
+    assert calls["axis_types"] == explicit
 
 
 # ------------------------------------------------------------- shard_map
 def test_shard_map_import_resolved():
-    """The shim found an implementation wherever this JAX keeps it
-    (top-level export on new builds, jax.experimental on the 0.4.x
-    line)."""
-    assert callable(compat._shard_map_impl)
-    assert compat._SHARD_MAP_PARAMS & {"check_vma", "check_rep"}
+    """The installed JAX exports ``shard_map`` at top level with the
+    ``check_vma`` spelling the helper passes."""
+    assert callable(jax.shard_map)
+    assert "check_vma" in inspect.signature(jax.shard_map).parameters
 
 
 def test_shard_map_behavioral():
@@ -87,38 +61,21 @@ def test_shard_map_behavioral():
 @pytest.mark.parametrize("params,expected_kwarg", [
     (frozenset({"f", "mesh", "in_specs", "out_specs", "check_vma"}),
      "check_vma"),
-    (frozenset({"f", "mesh", "in_specs", "out_specs", "check_rep"}),
-     "check_rep"),
 ])
 def test_shard_map_flag_renamed_per_signature(monkeypatch, params,
                                               expected_kwarg):
-    """``check_vma`` must land as whichever spelling the installed build
-    accepts (check_rep on 0.4.x, check_vma after the rename)."""
+    """``check_vma`` reaches ``jax.shard_map`` under that name."""
     seen = {}
 
     def fake_impl(f, mesh=None, in_specs=None, out_specs=None, **kwargs):
         seen.update(kwargs)
         return "wrapped"
 
-    monkeypatch.setattr(compat, "_shard_map_impl", fake_impl)
-    monkeypatch.setattr(compat, "_SHARD_MAP_PARAMS", params)
+    assert expected_kwarg in params
+    monkeypatch.setattr(jax, "shard_map", fake_impl)
     assert compat.shard_map(lambda x: x, mesh="m", in_specs=(),
                             out_specs=(), check_vma=False) == "wrapped"
     assert seen == {expected_kwarg: False}
-
-
-def test_shard_map_flag_dropped_when_signature_has_neither(monkeypatch):
-    seen = {}
-
-    def fake_impl(f, mesh=None, in_specs=None, out_specs=None, **kwargs):
-        seen.update(kwargs)
-        return "wrapped"
-
-    monkeypatch.setattr(compat, "_shard_map_impl", fake_impl)
-    monkeypatch.setattr(compat, "_SHARD_MAP_PARAMS",
-                        frozenset({"f", "mesh", "in_specs", "out_specs"}))
-    compat.shard_map(lambda x: x, mesh="m", in_specs=(), out_specs=())
-    assert seen == {}
 
 
 # --------------------------------------------------------- cost_analysis
@@ -132,15 +89,12 @@ class _FakeCompiled:
 
 def test_cost_analysis_list_vs_dict():
     flat = {"flops": 8.0, "bytes accessed": 64.0}
-    assert compat.cost_analysis(_FakeCompiled([flat])) == flat   # 0.4.x
-    assert compat.cost_analysis(_FakeCompiled(flat)) == flat     # new
-    assert compat.cost_analysis(_FakeCompiled(None)) == {}
-    assert compat.cost_analysis(_FakeCompiled([])) == {}
-    assert compat.cost_analysis(_FakeCompiled(({"a": 1.0},))) == {"a": 1.0}
+    assert compat.cost_analysis(_FakeCompiled(flat)) == flat
 
 
 def test_cost_analysis_real_compiled():
     compiled = jax.jit(lambda x: x * 2 + 1).lower(
         jnp.arange(8, dtype=jnp.float32)).compile()
     ca = compat.cost_analysis(compiled)
-    assert isinstance(ca, dict)       # flat on every build, never a list
+    assert isinstance(ca, dict)       # flat, never a list
+    assert ca["flops"] > 0
