@@ -27,8 +27,6 @@ from typing import List, Optional
 
 from repro.core.completable import ArrayOp, Completable
 from repro.core.scheduler import Scheduler
-from repro.obs import events as _obs_events
-from repro.obs import tracer as _obs
 
 
 class Progress:
@@ -77,8 +75,6 @@ class Progress:
     def scan(self) -> None:
         """Discover completions of poll-mode ops (cheap, lock-sliced)."""
         self.stats["poll_scans"] += 1
-        tr = _obs.TRACE
-        t0 = tr.now() if tr is not None else 0.0
         with self._poll_lock:
             ops = list(self._poll_ops)
         done_ops = [op for op in ops if op.done()]  # done() fires hooks
@@ -87,11 +83,6 @@ class Progress:
             with self._poll_lock:
                 self._poll_ops = [op for op in self._poll_ops
                                   if id(op) not in done_set]
-            # only fruitful scans are recorded — empty polls would swamp
-            # the ring without telling the timeline anything
-            if tr is not None:
-                tr.evt(_obs_events.PROGRESS_SCAN, -1, "core", ts=t0,
-                       dur=tr.now() - t0, meta=len(done_ops))
 
     @property
     def watched(self) -> int:

@@ -18,6 +18,14 @@ Two families:
   across the disagg transport, decode-step completion, token delivery,
   and the router's shadow-replay link (``req.link`` lets the exporter
   merge a shadow's events onto the original request's track).
+* ``serve.*`` — the serve loop's own phases (``ServeEngine.step``):
+  admission, step dispatch, the progress call that discovers completions
+  and runs their continuations, and each in-order commit of a finished
+  step; a ``serve.stall`` marks a phase longer than ``STALL_S``. Each
+  phase's seconds also feed a ``(LOOP, phase)`` histogram.
+* ``obs.anchor`` — a stamp of the tracer's clock taken inside a profiler
+  annotation of the same name (``Tracer.anchor``), which maps ``obs``
+  time onto a device trace's clock.
 """
 from __future__ import annotations
 
@@ -30,7 +38,6 @@ CONT_POSTED = "cont.posted"
 CONT_READY = "cont.ready"          # op group complete -> READY
 CONT_ENQUEUED = "cont.enqueued"    # pushed to a ready queue
 CONT_RAN = "cont.ran"              # span: callback execution
-PROGRESS_SCAN = "progress.scan"    # span: a poll scan that found work
 
 # serve layer (rid = request id)
 REQ_SUBMIT = "req.submit"          # entered a tier's intake
@@ -46,6 +53,24 @@ REQ_DELIVER = "req.deliver"        # tokens published to the request
 REQ_FINISH = "req.finish"          # terminal state reached
 REQ_LINK = "req.link"              # rid = shadow id, meta = original id
 REQ_REPLAY = "req.replay"          # failover: requeued for replay
+
+# serve loop (rid = -1); a phase records when it did something or stalled
+LOOP_ADMIT = "admit"               # placement, prefill/suffix/scatter
+LOOP_DISPATCH = "dispatch"         # sweep, table upload, launch, pushes
+LOOP_PROGRESS = "progress"         # poll scan + the continuations it runs
+LOOP_COMMIT = "commit"             # one step's in-order commit
+SERVE_KINDS = {phase: "serve." + phase for phase in
+               (LOOP_ADMIT, LOOP_DISPATCH, LOOP_PROGRESS, LOOP_COMMIT)}
+SERVE_STALL = "serve.stall"        # span: a phase longer than STALL_S
+#: a loop phase longer than this (seconds) records a ``serve.stall``:
+#: above a whole decode step at the benchmark's widths (a dispatch can
+#: span one) and below the shortest loop stall seen, 0.9 s
+STALL_S = 0.5
+#: histogram edge of the loop phases: ``(LOOP, phase)``
+LOOP = "loop"
+
+# clock anchor: rid = -1, ts = tracer clock inside a profiler annotation
+OBS_ANCHOR = "obs.anchor"
 
 #: lifecycle-edge histogram names, in causal order. ``complete_to_run``
 #: is the paper's notification latency (op complete -> callback ran).

@@ -6,7 +6,7 @@
 original via ``req.link``), with a named "thread" row per emitting
 component, so a request's admission -> prefill -> ship/import -> steps
 -> delivery reads left-to-right on one track. Runtime-internal events
-(``cont.*``, ``progress.*``) land in a shared pid 0 process keyed by
+(``cont.*``, ``serve.*``, ``obs.*``) land in a shared pid 0 process keyed by
 real thread id.
 
 ``prometheus_text`` renders a point-in-time text-exposition snapshot:

@@ -34,7 +34,9 @@ from typing import Dict, List, Optional, Tuple
 from repro.obs.buffer import TraceBuffer
 from repro.obs.events import (CONT_RAN, EDGE_COMPLETE_TO_ENQUEUE,
                               EDGE_COMPLETE_TO_RUN, EDGE_ENQUEUE_TO_RUN,
-                              EDGE_POST_TO_COMPLETE, Event, policy_key)
+                              EDGE_POST_TO_COMPLETE, LOOP, OBS_ANCHOR,
+                              SERVE_KINDS, SERVE_STALL, STALL_S, Event,
+                              policy_key)
 from repro.obs.hist import Histogram
 
 DEFAULT_CAPACITY = 1 << 16
@@ -57,6 +59,7 @@ class Tracer:
         self._hist: Dict[Tuple[str, str], Histogram] = {}
         self._hist_lock = threading.Lock()
         self.t0 = self.clock()
+        self.stopped = False   # set by ``stop()``
 
     # ------------------------------------------------------------ recording
     def now(self) -> float:
@@ -85,6 +88,47 @@ class Tracer:
         if ts is None:
             ts = self.clock()
         self._buf().record((ts, dur, kind, rid, src, meta))
+
+    def loop_span(self, phase: str, t0: float, did: bool = True, *,
+                  meta=None, context=None) -> float:
+        """Close the serve-loop phase ``phase`` begun at ``t0``; returns
+        the clock now, where the next phase begins.
+
+        A phase that did something (``did``), or ran past ``STALL_S``,
+        records its ``serve.<phase>`` span and its ``(LOOP, phase)``
+        histogram; an empty spin of the loop records nothing. Past
+        ``STALL_S`` it also records a ``serve.stall`` span whose meta is
+        the phase plus ``context()``, called only then. A stopped session
+        records nothing: a loop step begun before ``stop()`` would
+        otherwise add a phase that ended after it."""
+        t1 = self.clock()
+        dur = t1 - t0
+        if self.stopped:
+            return t1
+        if did or dur > STALL_S:
+            self.evt(SERVE_KINDS[phase], -1, "engine", ts=t0, dur=dur,
+                     meta=meta)
+            self.observe(LOOP, phase, dur)
+            if dur > STALL_S:
+                info = {"phase": phase}
+                if context is not None:
+                    info.update(context())
+                self.evt(SERVE_STALL, -1, "engine", ts=t0, dur=dur,
+                         meta=info)
+        return t1
+
+    def anchor(self) -> float:
+        """Stamp this clock inside a profiler annotation ``obs.anchor``
+        and record the stamp as an ``obs.anchor`` event; returns it.
+
+        Taken while a device trace runs, two anchors map ``obs`` time
+        (and ``Request`` stamps, on the same clock) onto the trace's: the
+        first gives the offset, the second how far the map drifts."""
+        import jax
+        with jax.profiler.TraceAnnotation(OBS_ANCHOR):
+            ts = self.clock()
+        self.evt(OBS_ANCHOR, -1, "obs", ts=ts)
+        return ts
 
     # ------------------------------------------------- lifecycle histograms
     def observe(self, edge: str, pkey: str, seconds: float) -> None:
@@ -157,6 +201,8 @@ def stop() -> Optional[Tracer]:
     global TRACE
     with _state_lock:
         tr, TRACE = TRACE, None
+        if tr is not None:
+            tr.stopped = True
         return tr
 
 

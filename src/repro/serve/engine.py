@@ -292,6 +292,9 @@ class ServeEngine:
         self._dispatched = 0
         self._committed = 0
         self._parked: dict = {}
+        # with ``obs`` on: the trace name of the last program launched
+        # (``_launch``), for the dispatch span and a ``serve.stall`` record
+        self._last_program = ""
         self._stalled_at: Optional[int] = None  # pages_in_use at last deferral
         self._retired: List[Request] = []
         self._lock = threading.Lock()         # guards _retired for readers
@@ -359,6 +362,13 @@ class ServeEngine:
         holds cannot change under it."""
         return jax.device_put(np.array(host), self.device)
 
+    def _launch(self, fn, *args):
+        """``fn(*args)``, a jitted program launch; with ``obs`` on, keeps
+        its name as a profiler trace shows it (``jit_<name>``)."""
+        if _obs.TRACE is not None:
+            self._last_program = "jit_" + fn.__name__
+        return fn(*args)
+
     def _after_device(self, out: Any, fn, meta: Any, priority: int) -> None:
         """Run ``fn(meta)`` once ``out`` is complete, in dispatch order.
 
@@ -378,10 +388,18 @@ class ServeEngine:
     def _commit(self, statuses, data) -> None:
         seq, fn, meta = data
         self._parked[seq] = (fn, meta)
+        tr = _obs.TRACE
         while self._committed in self._parked:
-            fn, meta = self._parked.pop(self._committed)
+            seq = self._committed
+            fn, meta = self._parked.pop(seq)
             self._committed += 1
-            fn(meta)
+            if tr is None:
+                fn(meta)
+            else:
+                t = tr.now()
+                fn(meta)
+                tr.loop_span(_obs_events.LOOP_COMMIT, t, meta=seq,
+                             context=self._stall_context)
 
     def _ensure_state(self) -> None:
         if self._tokens is None:
@@ -444,7 +462,8 @@ class ServeEngine:
         if req.max_new_tokens == 1:
             # single-token request: prefill answers it outright; it never
             # occupies a decode slot (nor, in paged mode, any pages)
-            logits, _ = self._prefill_fn(self.params, {"tokens": prompt})
+            logits, _ = self._launch(self._prefill_fn, self.params,
+                                     {"tokens": prompt})
             first = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
             req.push_device_token(first[0])
             self.stats["prefills"] += 1
@@ -459,7 +478,8 @@ class ServeEngine:
                 return False
             first = placed
         else:
-            logits, cache1 = self._prefill_fn(self.params, {"tokens": prompt})
+            logits, cache1 = self._launch(self._prefill_fn, self.params,
+                                          {"tokens": prompt})
             first = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
 
         slot = free.pop(0)
@@ -550,28 +570,30 @@ class ServeEngine:
                 # gather table itself — the prefix is page-aligned, so
                 # every written entry is request-owned; shared pages and
                 # padding rows are untouched (n_valid masks the pad)
-                logits, pool.arrays = self._suffix_fn(
-                    self.params, pool.arrays, suffix,
+                logits, pool.arrays = self._launch(
+                    self._suffix_fn, self.params, pool.arrays, suffix,
                     self._put([start]), self._padded_table(table)[None],
                     self._put([tail]))
             else:
-                logits, pool.arrays = self._suffix_fn(
-                    self.params, pool.arrays, suffix, jnp.int32(start),
-                    self._padded_table(table), self._put(scat))
+                logits, pool.arrays = self._launch(
+                    self._suffix_fn, self.params, pool.arrays, suffix,
+                    jnp.int32(start), self._padded_table(table),
+                    self._put(scat))
             self.stats["suffix_steps"] += 1
             self.stats["suffix_tokens"] += tail
             first = jnp.argmax(logits[:, tail - 1], axis=-1).astype(jnp.int32)
         else:
             # cold: dense prefill over the whole prompt, then blit the
             # prompt pages into the pool in one scatter
-            logits, cache1 = self._prefill_fn(self.params, {"tokens": prompt})
+            logits, cache1 = self._launch(self._prefill_fn, self.params,
+                                          {"tokens": prompt})
             first = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
             scatter_table = np.full(self._table_pages, pool.null_page,
                                     np.int32)
             n_prompt_pages = pages_for(plen, ps)
             scatter_table[:n_prompt_pages] = table[:n_prompt_pages]
-            pool.arrays = self._scatter_fn(pool.arrays, cache1,
-                                           self._put(scatter_table))
+            pool.arrays = self._launch(self._scatter_fn, pool.arrays,
+                                       cache1, self._put(scatter_table))
         pool.register_prefix(req.prompt, table)
         return first
 
@@ -662,16 +684,17 @@ class ServeEngine:
             # lanes too and parks their garbage writes on the null page)
             nv = np.zeros(self.max_batch, np.int32)
             nv[[i for i, _ in live]] = 1
-            nxt, self.pool.arrays = self._decode_fn(
-                self.params, self.pool.arrays, self._tokens,
-                self._put(self._pos), self._device_tables(), self._put(nv))
+            nxt, self.pool.arrays = self._launch(
+                self._decode_fn, self.params, self.pool.arrays,
+                self._tokens, self._put(self._pos), self._device_tables(),
+                self._put(nv))
         elif self.paged:
-            nxt, self.pool.arrays = self._decode_fn(
-                self.params, self.pool.arrays, self._tokens,
-                self._put(self._pos), self._device_tables())
+            nxt, self.pool.arrays = self._launch(
+                self._decode_fn, self.params, self.pool.arrays,
+                self._tokens, self._put(self._pos), self._device_tables())
         else:
-            nxt, self._cache = self._decode_fn(
-                self.params, self._cache, self._tokens,
+            nxt, self._cache = self._launch(
+                self._decode_fn, self.params, self._cache, self._tokens,
                 self._put(self._pos))
         # the jitted step surfaces per-slot next tokens directly: (S, 1)
         self._tokens = nxt[..., None]                       # (S, 1, 1)
@@ -785,8 +808,8 @@ class ServeEngine:
             nv = np.zeros(S, np.int32)
             for i, _ in live:
                 nv[i] = 1 + n_drafts[i]
-            emitted, accepts, self.pool.arrays = self._verify_fn(
-                self.params, self.pool.arrays, tokens,
+            emitted, accepts, self.pool.arrays = self._launch(
+                self._verify_fn, self.params, self.pool.arrays, tokens,
                 self._put(self._pos), self._device_tables(), self._put(nv))
         else:
             # write tables: rows for idle / still-verifying slots stay all
@@ -797,8 +820,8 @@ class ServeEngine:
                 wtables[i] = self.pool.write_table(r.page_ids,
                                                    int(self._pos[i]),
                                                    self._verify_pages)
-            emitted, accepts, self.pool.arrays = self._verify_fn(
-                self.params, self.pool.arrays, tokens,
+            emitted, accepts, self.pool.arrays = self._launch(
+                self._verify_fn, self.params, self.pool.arrays, tokens,
                 self._put(self._pos), self._device_tables(),
                 self._put(wtables), self._put(n_drafts))
         self._verifying.update(i for i, _ in live)
@@ -959,16 +982,48 @@ class ServeEngine:
     def step(self) -> bool:
         """One loop iteration: admit, dispatch (windowed), progress.
 
+        With ``obs`` on, each phase closes a ``Tracer.loop_span``: an
+        admission that placed a request, a dispatch that launched a step
+        (meta: its commit sequence number and program), a progress call
+        that committed a step, and any phase past the stall threshold.
+
         Returns True if any work was started or completed.
         """
+        tr = _obs.TRACE
+        t = tr.now() if tr is not None else 0.0
         admitted = self._admit()
+        if tr is not None:
+            t = tr.loop_span(_obs_events.LOOP_ADMIT, t, bool(admitted),
+                             context=self._stall_context)
         dispatched = False
         if self._inflight < self.max_inflight:
+            seq = self._dispatched
             dispatched = self._dispatch_step()
+            if tr is not None:
+                t = tr.loop_span(_obs_events.LOOP_DISPATCH, t, dispatched,
+                                 meta=(seq, self._last_program)
+                                 if dispatched else None,
+                                 context=self._stall_context)
         before = self.stats["retired"]
+        committed = self._committed
         self.engine.tick()   # discover step completions, run continuations
+        if tr is not None:
+            tr.loop_span(_obs_events.LOOP_PROGRESS, t,
+                         self._committed != committed,
+                         context=self._stall_context)
         return bool(admitted) or dispatched or \
             self.stats["retired"] != before
+
+    def _stall_context(self) -> dict:
+        """What a ``serve.stall`` record carries beside its phase: the
+        last step program launched and the device's memory counters."""
+        out = {"program": self._last_program}
+        mem = self.device.memory_stats() or {}
+        for key in ("bytes_in_use", "peak_bytes_in_use",
+                    "largest_free_block_bytes"):
+            if key in mem:
+                out[key] = int(mem[key])
+        return out
 
     @property
     def idle(self) -> bool:

@@ -13,6 +13,11 @@ scatters back to the pool — shared prefix pages are read, never written.
 Shapes are static and bounded: tables are null-page padded to
 ``max_pages`` (decode/scatter compile once) and suffix tails are padded
 to a page multiple by the engine (at most ``max_pages`` suffix shapes).
+
+The jitted function's name is the program's name in a profiler trace:
+decode steps are ``step`` (``jit_step``), suffix prefills
+``suffix_step`` and speculative verifies ``verify_step``, so each
+program's device time reads apart.
 """
 from __future__ import annotations
 
@@ -181,8 +186,8 @@ def make_paged_verify_step(cfg: ModelConfig, page_size: int,
     decode_one = make_decode_step(cfg)
     n_wpages = 1 + pages_for(n_draft, page_size)
 
-    def step(params, pool, tokens, positions, tables, write_tables,
-             n_drafts):
+    def verify_step(params, pool, tokens, positions, tables, write_tables,
+                    n_drafts):
         def one(token, pos, table, k):
             cache = _gather_pages(pool, table, page_size)
             logits, new_cache = decode_one(params, cache, token, pos)
@@ -209,7 +214,7 @@ def make_paged_verify_step(cfg: ModelConfig, page_size: int,
             return p.at[:, write_tables.reshape(-1)].set(pg)
 
         return emitted, accepts, jax.tree_util.tree_map(scat, pool, pages)
-    return step
+    return verify_step
 
 
 def make_paged_suffix_step(cfg: ModelConfig, page_size: int) -> Callable:
@@ -223,14 +228,14 @@ def make_paged_suffix_step(cfg: ModelConfig, page_size: int) -> Callable:
     content back — an identity write."""
     decode_one = make_decode_step(cfg)
 
-    def step(params, pool, tokens, pos, gather_table, scatter_table):
+    def suffix_step(params, pool, tokens, pos, gather_table, scatter_table):
         cache = _gather_pages(pool, gather_table, page_size)
         logits, new_cache = decode_one(params, cache, tokens, pos)
 
         def one(p, c):
             return p.at[:, scatter_table].set(_pool_pages(c[:, 0], page_size))
         return logits, jax.tree_util.tree_map(one, pool, new_cache)
-    return step
+    return suffix_step
 
 
 # ------------------------------------------------------- fused paged steps
@@ -274,7 +279,7 @@ def make_fused_paged_verify_step(cfg: ModelConfig, page_size: int,
     causal horizon until the next verify window overwrites them."""
     K = n_draft
 
-    def step(params, pool, tokens, positions, tables, n_valid):
+    def verify_step(params, pool, tokens, positions, tables, n_valid):
         tok = tokens[:, 0, :]                                # (S, 1+K)
         logits, new_pool = lm.lm_paged_decode(
             params, tok, cfg, pool, positions, tables, n_valid,
@@ -285,7 +290,7 @@ def make_fused_paged_verify_step(cfg: ModelConfig, page_size: int,
               & (jnp.arange(K, dtype=jnp.int32)[None, :] < k[:, None]))
         accepts = jnp.sum(jnp.cumprod(ok.astype(jnp.int32), axis=1), axis=1)
         return emitted, accepts.astype(jnp.int32), new_pool
-    return step
+    return verify_step
 
 
 def make_fused_paged_suffix_step(cfg: ModelConfig, page_size: int) -> Callable:
@@ -295,11 +300,11 @@ def make_fused_paged_suffix_step(cfg: ModelConfig, page_size: int) -> Callable:
     ``n_valid`` its real length — padded rows are never written and the
     clamped causal horizon keeps them off unwritten positions, while
     shared prefix pages (positions < pos) are read, never written."""
-    def step(params, pool, tokens, positions, tables, n_valid):
+    def suffix_step(params, pool, tokens, positions, tables, n_valid):
         return lm.lm_paged_decode(
             params, tokens, cfg, pool, positions, tables, n_valid,
             page_size=page_size)
-    return step
+    return suffix_step
 
 
 def make_prefill_scatter(cfg: ModelConfig, page_size: int) -> Callable:

@@ -75,8 +75,9 @@ def test_colocated_timeline_complete_and_ordered(small_model, tmp_path):
         assert all(prefill.ts <= ev.ts for ev in steps)
 
     # the runtime's own edges rode along: all four lifecycle histograms
+    # and the serve loop's phases
     assert ({edge for edge, _ in rec.histograms}
-            == set(E.LIFECYCLE_EDGES))
+            == set(E.LIFECYCLE_EDGES) | {E.LOOP})
     cause = rec.cause_summary()
     assert cause["requests"] == len(reqs)
     assert cause["compute_ms_mean"] > 0.0
@@ -90,6 +91,45 @@ def test_colocated_timeline_complete_and_ordered(small_model, tmp_path):
     assert {r.req_id + 1 for r in reqs} <= pids
     assert any(r_["ph"] == "X" for r_ in doc["traceEvents"])
     assert path.endswith("trace.json")
+
+
+# -------------------------------------------------------- serve-loop spans
+def test_loop_phases_carry_the_commit_sequence(small_model):
+    """Each dispatch span names the sequence number and program of the
+    step it launched; that step's commit span carries the same number,
+    begins after the dispatch ended, and every phase feeds its
+    histogram."""
+    cfg, params = small_model
+    tr = obs.start()
+    reqs = serve_requests(cfg, params, [Request(p, 6) for p in PROMPTS],
+                          timeout=300, paged=True, **KW)
+    tracer_mod.stop()
+    assert all(r.req_state is RequestState.FINISHED for r in reqs)
+    events = tr.drain()
+    kinds = {ev.kind for ev in events}
+    assert set(E.SERVE_KINDS.values()) <= kinds
+    dispatch = [ev for ev in events if ev.kind == "serve.dispatch"]
+    commits = {ev.meta: ev for ev in events if ev.kind == "serve.commit"}
+    assert dispatch and all(ev.meta[1] == "jit_step" for ev in dispatch)
+    for ev in dispatch:
+        assert commits[ev.meta[0]].ts >= ev.ts + ev.dur
+    # commits run in dispatch order, one per registered step
+    seqs = [ev.meta for ev in events if ev.kind == "serve.commit"]
+    assert seqs == sorted(seqs) == list(range(len(seqs)))
+    hist = tr.histograms()
+    for phase in E.SERVE_KINDS:
+        assert hist[(E.LOOP, phase)].count > 0
+
+
+def test_untraced_loop_calls_no_span_helper(small_model, monkeypatch):
+    cfg, params = small_model
+    calls = []
+    monkeypatch.setattr(tracer_mod.Tracer, "loop_span",
+                        lambda *a, **k: calls.append(a))
+    reqs = serve_requests(cfg, params, [Request(p, 6) for p in PROMPTS],
+                          timeout=300, paged=True, **KW)
+    assert all(r.req_state is RequestState.FINISHED for r in reqs)
+    assert tracer_mod.TRACE is None and calls == []
 
 
 # ------------------------------------------------------------ disagg tier

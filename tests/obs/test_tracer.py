@@ -132,6 +132,54 @@ def test_lifecycle_edges_ordered_and_histogrammed():
         assert h.total >= 0.0
 
 
+# ------------------------------------------------------- serve-loop spans
+def test_loop_span_records_span_and_histogram():
+    tr = obs.start()
+    t1 = tr.loop_span(E.LOOP_DISPATCH, tr.now(), meta=(3, "jit_step"))
+    assert t1 <= tr.now()
+    (ev,) = tr.drain()
+    assert ev.kind == "serve.dispatch" and ev.meta == (3, "jit_step")
+    assert ev.dur >= 0.0 and ev.ts + ev.dur == pytest.approx(t1)
+    assert set(tr.histograms()) == {(E.LOOP, E.LOOP_DISPATCH)}
+    assert tr.histograms()[(E.LOOP, E.LOOP_DISPATCH)].count == 1
+
+
+def test_loop_span_of_an_empty_phase_records_nothing():
+    tr = obs.start()
+    t0 = tr.now()
+    assert tr.loop_span(E.LOOP_PROGRESS, t0, did=False,
+                        context=lambda: pytest.fail("read")) >= t0
+    assert tr.drain() == [] and tr.histograms() == {}
+
+
+@pytest.mark.parametrize("did", [True, False])
+def test_a_phase_past_the_stall_threshold_records_one_stall(did):
+    tr = obs.start()
+    t0 = tr.now() - 2 * E.STALL_S
+    tr.loop_span(E.LOOP_ADMIT, t0, did,
+                 context=lambda: {"program": "jit_prefill"})
+    events = tr.drain()
+    assert [ev.kind for ev in events] == ["serve.admit", E.SERVE_STALL]
+    stall = events[1]
+    assert stall.meta == {"phase": E.LOOP_ADMIT, "program": "jit_prefill"}
+    assert stall.ts == t0 and stall.dur > E.STALL_S
+    h = tr.histograms()[(E.LOOP, E.LOOP_ADMIT)]
+    assert h.count == 1 and h.max > E.STALL_S * 1e6
+
+
+def test_a_stopped_tracer_takes_no_loop_span():
+    """A loop step begun before ``stop()`` ends after it: what it closes
+    then is not the session's."""
+    tr = obs.start()
+    t0 = tr.now()
+    tr.loop_span(E.LOOP_DISPATCH, t0)
+    assert obs.stop() is tr
+    assert tr.loop_span(E.LOOP_DISPATCH, t0 - 2 * E.STALL_S) >= t0
+    assert [ev.kind for ev in tr.drain()] == ["serve.dispatch"]
+    h = tr.histograms()[(E.LOOP, E.LOOP_DISPATCH)]
+    assert h.count == 1 and h.max < E.STALL_S * 1e6
+
+
 # ------------------------------------------------------------- exporters
 def test_chrome_trace_tracks_and_phases():
     events = [

@@ -162,3 +162,39 @@ def test_device_table_cache_incremental(small_model):
         assert not eng._tables_dirty
     finally:
         eng.shutdown()
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_step_programs_lower_under_their_own_names(small_model, fused):
+    """Decode, suffix prefill and verify are three programs, and a
+    profiler trace names each apart: ``jit_step`` is the decode step
+    alone."""
+    import jax.numpy as jnp
+    cfg, params = small_model
+    eng = ServeEngine(cfg, params, max_batch=3, max_cache_len=64,
+                      page_size=8, total_pages=24, max_seq_len=48,
+                      speculate=2, fused=fused)
+    try:
+        eng._ensure_state()
+        S, T, K = eng.max_batch, eng._table_pages, eng.speculate
+        pool = eng.pool.arrays
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+        if fused:
+            decode = (i32(S, 1, 1), i32(S), i32(S, T), i32(S))
+            suffix = (i32(1, 8), i32(1), i32(1, T), i32(1))
+            verify = (i32(S, 1, 1 + K), i32(S), i32(S, T), i32(S))
+        else:
+            decode = (i32(S, 1, 1), i32(S), i32(S, T))
+            suffix = (i32(1, 8), i32(), i32(T), i32(T))
+            verify = (i32(S, 1, 1 + K), i32(S), i32(S, T),
+                      i32(S, eng._verify_pages), i32(S))
+        for name, fn, args in (("jit_step", eng._decode_fn, decode),
+                               ("jit_suffix_step", eng._suffix_fn, suffix),
+                               ("jit_verify_step", eng._verify_fn, verify)):
+            text = fn.lower(params, pool, *args).as_text()
+            assert f"module @{name} " in text, name
+    finally:
+        eng.shutdown()
