@@ -10,9 +10,9 @@ unfused serve path needed three stages for:
    ever materialized.
 2. **attend** — flash-style online softmax over the page axis (innermost
    grid dimension); the (m, l, acc) state lives in VMEM scratch that
-   persists across pages of the same (slot, kv-head). Causal masking is
-   positional (``kpos <= qpos``), so null-page garbage in table tails and
-   stale speculative rows are never attended.
+   persists across pages of the same (slot, kv-head block). Causal
+   masking is positional (``kpos <= qpos``), so null-page garbage in
+   table tails and stale speculative rows are never attended.
 3. **accept-masked KV write** — the ``1 + K`` window's new KV rows are
    inserted into the loaded page in-register (rows ``j < n_valid`` whose
    position falls inside the page) and every gathered page is written
@@ -33,23 +33,37 @@ On-device page-table memory layout (pinned contract, shared with
 ``ref.py`` and ``serve.kv_cache.PagePool``):
 
 * pool (one layer): ``(total_pages + 1, KV, page_size, head_dim)`` —
-  page-major, kv-head-minor-to-page, so one (page, kv head) block is a
-  whole ``(page_size, head_dim)`` tile. Page index ``total_pages`` is the
-  scratch ("null") page; table padding points at it so idle slots and
-  table tails read/write garbage there.
+  page-major, so one page of ``kb`` consecutive kv heads is one
+  contiguous ``(kb, page_size, head_dim)`` block. Page index
+  ``total_pages`` is the scratch ("null") page; table padding points at
+  it so idle slots and table tails read/write garbage there.
 * ``tables (S, T)`` int32 — entry ``p`` holds the pool page owning
   absolute token positions ``[p*page_size, (p+1)*page_size)``.
 * ``positions (S,)`` int32 — absolute position of window row 0.
 * ``n_valid (S,)`` int32 — rows actually written (0 = idle slot).
 
+Grid ``(S, KV // kb, T)``: one step per (slot, block of ``kb`` kv heads,
+table entry). The pipeline's cost per grid step, not the page bytes, sets
+a decode step's pace, so the step carries as many kv heads as fit:
+:func:`kv_fold` takes the largest divisor of ``KV`` whose per-step VMEM
+footprint (q, o, new-KV and pool blocks double-buffered, the f32
+accumulator and scores) fits ``VMEM_BUDGET``. Decode and verify windows
+fold every kv head; a long suffix-prefill window keeps a narrow block,
+down to ``kb = 1``.
+
 TPU shaping: every block's last two dims are whole array dims — pool
-blocks ``(page_size, head_dim)``, query/output blocks ``(W*G, head_dim)``
-(the ``G`` query heads of one kv head are flattened into rows outside the
-kernel), new-KV blocks ``(W, head_dim)`` — which the TPU tiling rule
-accepts at any page size and head dim. Every visited page is re-written
-(read-modify-write through the alias), trading one page of write
-bandwidth per gathered page for the one-kernel structure; a
-write-window-only output spec is the follow-up optimization.
+blocks ``(kb, page_size, head_dim)``, query/output blocks
+``(kb*W*G, head_dim)`` (the ``G`` query heads of each of the ``kb`` kv
+heads are flattened into rows ``h*W*G + w*G + g`` outside the kernel),
+new-KV blocks ``(kb, W, head_dim)`` — which the TPU tiling rule accepts
+at any page size and head dim. Attention is one block-diagonal dot pair
+per step: every query row against the page's ``kb*page_size`` key rows,
+scores of another kv head masked off before the softmax. The insert's
+one-hot dots run only on a page that holds a window row; every other
+visited page round-trips unchanged (read-modify-write through the
+alias), trading one page of write bandwidth per gathered page for the
+one-kernel structure; a write-window-only output spec is the follow-up
+optimization.
 """
 from __future__ import annotations
 
@@ -62,19 +76,40 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# VMEM a grid step's blocks and f32 temporaries may take when choosing
+# kv heads per step: a quarter of a v5e's 16-MiB default scoped VMEM
+VMEM_BUDGET = 4 << 20
+
+
+def kv_fold(kv_heads: int, window: int, groups: int, head_dim: int,
+            page_size: int, itemsize: int) -> int:
+    """kv heads per grid step: the largest divisor of ``kv_heads`` whose
+    per-step VMEM footprint fits ``VMEM_BUDGET``, else 1."""
+    for kb in range(kv_heads, 0, -1):
+        if kv_heads % kb:
+            continue
+        rows, cols = kb * window * groups, kb * page_size
+        need = (rows * head_dim * (2 * 2 * itemsize + 4)    # q, o x2; acc
+                + 2 * 2 * kb * window * head_dim * itemsize  # new k/v x2
+                + 4 * 2 * cols * head_dim * itemsize       # pool in/out x2
+                + 2 * rows * cols * 4)                     # scores, probs
+        if need <= VMEM_BUDGET:
+            return kb
+    return 1
 
 
 def _paged_kernel(tab_ref, pos_ref, nv_ref,          # scalar prefetch
                   q_ref, kn_ref, vn_ref, kp_ref, vp_ref,
                   o_ref, ko_ref, vo_ref,
                   m_scr, l_scr, acc_scr, *,
-                  scale: float, page_size: int, window: int, groups: int):
+                  scale: float, page_size: int, window: int, groups: int,
+                  fold: int):
     s = pl.program_id(0)
     p = pl.program_id(2)
     n_p = pl.num_programs(2)
     pos = pos_ref[s]
     nv = nv_ref[s]
-    W, ps, G = window, page_size, groups
+    W, ps, G, kb = window, page_size, groups, fold
     page_start = p * ps        # absolute position of the page's first row
 
     @pl.when(p == 0)
@@ -83,43 +118,49 @@ def _paged_kernel(tab_ref, pos_ref, nv_ref,          # scalar prefetch
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    k = kp_ref[0, 0]                                     # (ps, hd)
-    v = vp_ref[0, 0]
-    kn = kn_ref[0, 0]                                    # (W, hd)
-    vn = vn_ref[0, 0]
-
-    # accept-masked in-register KV insert: page row t receives window row
-    # j (absolute position pos + j) when pos + j - page_start == t AND
-    # j < n_valid. A (ps, W) one-hot matmul keeps the select vectorized;
-    # 0/1 weights make it exact.
-    tt = jax.lax.broadcasted_iota(jnp.int32, (ps, W), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (ps, W), 1)
-    ohf = (((pos + jj - page_start) == tt) & (jj < nv)).astype(jnp.float32)
-    hit = jnp.max(ohf, axis=1, keepdims=True) > 0.0      # (ps, 1)
-    k = jnp.where(hit, jnp.dot(ohf, kn.astype(jnp.float32)).astype(k.dtype),
-                  k)
-    v = jnp.where(hit, jnp.dot(ohf, vn.astype(jnp.float32)).astype(v.dtype),
-                  v)
-
     # unconditional writeback: the output block aliases the pool, so
     # untouched pages round-trip their own content and inserted rows land
     # in place (identical stores for pages shared across slots; the null
     # page collects garbage by contract)
-    ko_ref[0, 0] = k
-    vo_ref[0, 0] = v
+    ko_ref[...] = kp_ref[...]
+    vo_ref[...] = vp_ref[...]
+
+    # accept-masked in-register KV insert, only on a page holding a window
+    # row: page row t receives window row j (absolute position pos + j)
+    # when pos + j - page_start == t AND j < n_valid. A (ps, W) one-hot
+    # matmul keeps the select vectorized; 0/1 weights make it exact.
+    @pl.when((nv > 0) & (page_start <= pos + nv - 1)
+             & (pos < page_start + ps))
+    def _insert():
+        tt = jax.lax.broadcasted_iota(jnp.int32, (ps, W), 0)
+        jj = jax.lax.broadcasted_iota(jnp.int32, (ps, W), 1)
+        ohf = (((pos + jj - page_start) == tt) & (jj < nv)).astype(
+            jnp.float32)
+        hit = jnp.max(ohf, axis=1, keepdims=True) > 0.0  # (ps, 1)
+        for h in range(kb):
+            for new_ref, out_ref in ((kn_ref, ko_ref), (vn_ref, vo_ref)):
+                rows = jnp.dot(ohf, new_ref[0, h].astype(jnp.float32))
+                out_ref[0, h] = jnp.where(
+                    hit, rows.astype(out_ref.dtype), out_ref[0, h])
 
     def _attend():
-        qf = q_ref[0, 0].astype(jnp.float32)             # (W*G, hd)
-        kf = k.astype(jnp.float32)
+        hd = q_ref.shape[-1]
+        qf = q_ref[0, 0].astype(jnp.float32)             # (kb*W*G, hd)
+        kf = ko_ref[0].astype(jnp.float32).reshape(kb * ps, hd)
+        vf = vo_ref[0].astype(jnp.float32).reshape(kb * ps, hd)
         sc = jax.lax.dot_general(
-            qf, kf, (((1,), (1,)), ((), ()))) * scale    # (W*G, ps)
-        # row r is query window row r // G; causal by absolute position,
-        # horizon clamped to the last written row (ref.py pins the same
-        # clamp: padding rows never read unwritten/null-page positions)
-        wrow = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 0) // G
-        qpos = pos + jnp.minimum(wrow, nv - 1)
-        kpos = page_start + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        sc = jnp.where(kpos <= qpos, sc, NEG_INF)
+            qf, kf, (((1,), (1,)), ((), ()))) * scale    # (kb*W*G, kb*ps)
+        # row r is window row (r % (W*G)) // G of kv head r // (W*G);
+        # column c is page row c % ps of kv head c // ps. Causal by
+        # absolute position, horizon clamped to the last written row
+        # (ref.py pins the same clamp: padding rows never read
+        # unwritten/null-page positions); another head's keys are masked
+        r = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 0)
+        c = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        qpos = pos + jnp.minimum((r % (W * G)) // G, nv - 1)
+        kpos = page_start + c % ps
+        sc = jnp.where((r // (W * G) == c // ps) & (kpos <= qpos), sc,
+                       NEG_INF)
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
         pe = jnp.exp(sc - m_new)
@@ -127,8 +168,7 @@ def _paged_kernel(tab_ref, pos_ref, nv_ref,          # scalar prefetch
         alpha = jnp.exp(m_prev - m_new)
         alpha = jnp.where(m_prev <= NEG_INF, 0.0, alpha)
         l_scr[...] = l_scr[...] * alpha + jnp.sum(pe, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
-            pe, v.astype(jnp.float32))
+        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(pe, vf)
         m_scr[...] = m_new
 
     # idle slots (nv == 0) and pages entirely in the future (null-padded
@@ -156,11 +196,15 @@ def paged_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     T = tables.shape[1]
     G = H // KV
     scale = hd ** -0.5 if scale is None else scale
+    kb = kv_fold(KV, W, G, hd, ps,
+                 max(q.dtype.itemsize, k_pages.dtype.itemsize))
+    R = kb * W * G                 # query rows of one (slot, head block)
 
-    # head-major layouts so one (slot, kv-head) grid step owns one block;
-    # the G query heads of a kv head become rows w*G + g
+    # head-major layouts so one (slot, kv-head block) grid step owns one
+    # block; query head g of kv head h at window row w becomes row
+    # (h % kb)*W*G + w*G + g of head block h // kb
     qt = q.reshape(S, W, KV, G, hd).transpose(0, 2, 1, 3, 4).reshape(
-        S, KV, W * G, hd)
+        S, KV // kb, R, hd)
     knt = k_new.transpose(0, 2, 1, 3)                    # (S, KV, W, hd)
     vnt = v_new.transpose(0, 2, 1, 3)
 
@@ -172,31 +216,31 @@ def paged_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(S, KV, T),
+        grid=(S, KV // kb, T),
         in_specs=[
-            pl.BlockSpec((1, 1, W * G, hd), _slot_map),
-            pl.BlockSpec((1, 1, W, hd), _slot_map),
-            pl.BlockSpec((1, 1, W, hd), _slot_map),
-            pl.BlockSpec((1, 1, ps, hd), _page_map),
-            pl.BlockSpec((1, 1, ps, hd), _page_map),
+            pl.BlockSpec((1, 1, R, hd), _slot_map),
+            pl.BlockSpec((1, kb, W, hd), _slot_map),
+            pl.BlockSpec((1, kb, W, hd), _slot_map),
+            pl.BlockSpec((1, kb, ps, hd), _page_map),
+            pl.BlockSpec((1, kb, ps, hd), _page_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, W * G, hd), _slot_map),
-            pl.BlockSpec((1, 1, ps, hd), _page_map),
-            pl.BlockSpec((1, 1, ps, hd), _page_map),
+            pl.BlockSpec((1, 1, R, hd), _slot_map),
+            pl.BlockSpec((1, kb, ps, hd), _page_map),
+            pl.BlockSpec((1, kb, ps, hd), _page_map),
         ],
         scratch_shapes=[
-            pltpu.VMEM((W * G, 1), jnp.float32),
-            pltpu.VMEM((W * G, 1), jnp.float32),
-            pltpu.VMEM((W * G, hd), jnp.float32),
+            pltpu.VMEM((R, 1), jnp.float32),
+            pltpu.VMEM((R, 1), jnp.float32),
+            pltpu.VMEM((R, hd), jnp.float32),
         ],
     )
     o, nk, nv_out = pl.pallas_call(
         functools.partial(_paged_kernel, scale=scale, page_size=ps,
-                          window=W, groups=G),
+                          window=W, groups=G, fold=kb),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((S, KV, W * G, hd), q.dtype),
+            jax.ShapeDtypeStruct((S, KV // kb, R, hd), q.dtype),
             jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
             jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
         ],

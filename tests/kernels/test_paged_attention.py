@@ -17,6 +17,17 @@ from repro.kernels.paged_attention import kernel as pa_kernel, ref as pa_ref
 
 TOL = dict(atol=2e-5, rtol=2e-5)
 
+# (KV, G, hd): the small shape first, then kv-head counts, group sizes
+# and head dims of the served models, so the kernel folds up to eight kv
+# heads into one grid step at a lane-wide (128) and a ragged (120) head
+# dim; ``ids`` keep the small shape's cases under their names
+SMALL = (2, 2, 16)
+FOLDED = [(2, 4, 128), (8, 8, 128), (8, 4, 120)]
+PS_HEADS = pytest.mark.parametrize(
+    "ps,heads",
+    [(4, SMALL), (8, SMALL)] + [(8, h) for h in FOLDED],
+    ids=["4", "8"] + [f"8-kv{kv}g{g}hd{hd}" for kv, g, hd in FOLDED])
+
 
 def _scenario(seed, S, W, H, KV, hd, ps, T, n_real, positions, n_valid,
               tables=None):
@@ -60,25 +71,27 @@ def _compare(args, null):
     return o_k, k_k, v_k
 
 
-@pytest.mark.parametrize("ps", [4, 8])
+@PS_HEADS
 @pytest.mark.parametrize("K", [0, 1, 2, 3])
-def test_verify_window_parity(ps, K):
+def test_verify_window_parity(ps, heads, K):
     """1+K verify windows at ragged per-slot positions, incl. an idle
     slot and a slot with a rejected tail (n_valid < W)."""
+    KV, G, hd = heads
     W = 1 + 3  # engine compiles one W for every slot; n_valid masks K
     positions = [ps + 1, 3 * ps - 1, 0, 2 * ps]
     n_valid = [1 + K, max(1, K), 0, 1 + K]
     args, tables, null = _scenario(
-        0, 4, W, 4, 2, 16, ps, 6, 12, positions, n_valid)
+        0, 4, W, KV * G, KV, hd, ps, 6, 12, positions, n_valid)
     _compare(args, null)
 
 
-@pytest.mark.parametrize("ps", [4, 8])
-def test_decode_parity(ps):
+@PS_HEADS
+def test_decode_parity(ps, heads):
     """W=1 plain decode, positions straddling page boundaries."""
+    KV, G, hd = heads
     positions = [0, ps - 1, ps, 2 * ps + 1]
     args, tables, null = _scenario(
-        1, 4, 1, 4, 2, 16, ps, 4, 10, positions, [1, 1, 1, 1])
+        1, 4, 1, KV * G, KV, hd, ps, 4, 10, positions, [1, 1, 1, 1])
     _compare(args, null)
 
 
@@ -88,6 +101,62 @@ def test_suffix_prefill_parity():
     W = 16  # padded to a page multiple
     args, tables, null = _scenario(2, 1, W, 4, 2, 16, ps, 6, 5, [8], [tail])
     _compare(args, null)
+
+
+def test_suffix_prefill_parity_narrow_fold():
+    """A suffix window too wide to fold every kv head walks blocks of
+    fewer heads (1 < kb < KV) with the same results."""
+    ps, tail, W, KV, G, hd = 8, 50, 64, 8, 8, 128
+    kb = pa_kernel.kv_fold(KV, W, G, hd, ps, 4)
+    assert 1 < kb < KV
+    args, tables, null = _scenario(2, 1, W, KV * G, KV, hd, ps, 10, 12,
+                                   [8], [tail])
+    _compare(args, null)
+
+
+@pytest.mark.parametrize("W,G,hd,itemsize", [
+    (1, 8, 128, 2), (4, 8, 128, 2), (1, 4, 120, 2), (1, 3, 64, 2),
+    (16, 3, 64, 2), (1024, 4, 128, 2), (64, 8, 128, 4)])
+@pytest.mark.parametrize("KV", [1, 2, 4, 6, 8])
+def test_kv_fold_divides_and_fits(KV, W, G, hd, itemsize):
+    """kb is always a divisor of KV; decode and verify windows fold every
+    kv head; a 1024-row suffix window at hd 128 folds fewer."""
+    kb = pa_kernel.kv_fold(KV, W, G, hd, 16, itemsize)
+    assert 1 <= kb <= KV and KV % kb == 0
+    if W <= 4:
+        assert kb == KV
+    if W == 1024 and KV > 1:
+        assert kb < KV
+
+
+@pytest.mark.parametrize("heads", FOLDED,
+                         ids=[f"kv{kv}g{g}hd{hd}" for kv, g, hd in FOLDED])
+def test_folded_pool_writes_only_window_rows(heads):
+    """With every kv head in one grid step, exactly the live slots'
+    window rows change in the real pages, for every kv head; an idle
+    slot's footprint is untouched."""
+    KV, G, hd = heads
+    ps, W = 8, 4
+    positions, n_valid = [ps - 2, 2 * ps + 3, ps], [3, 0, 4]
+    args, tables, null = _scenario(8, 3, W, KV * G, KV, hd, ps, 5, 14,
+                                   positions, n_valid)
+    _, k_k, v_k = _compare(args, null)
+    expect = np.zeros((null, KV, ps), bool)
+    for s in range(3):
+        for j in range(n_valid[s]):
+            at = positions[s] + j
+            expect[tables[s, at // ps], :, at % ps] = True
+    for new, old, rows in ((k_k, args["k_pages"], args["k_new"]),
+                           (v_k, args["v_pages"], args["v_new"])):
+        new, old = np.asarray(new)[:null], np.asarray(old)[:null]
+        changed = np.any(new != old, axis=-1)
+        np.testing.assert_array_equal(changed, expect)
+        for s in range(3):
+            for j in range(n_valid[s]):
+                at = positions[s] + j
+                np.testing.assert_array_equal(
+                    new[tables[s, at // ps], :, at % ps],
+                    np.asarray(rows)[s, j])
 
 
 def test_idle_slot_writes_nothing_outputs_zero():

@@ -60,17 +60,35 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
-@pytest.mark.parametrize("S,W", [(8, 1), (1, 16)], ids=["decode", "suffix"])
-def test_paged_attention_compiles(one_chip, S, W):
-    cfg = get_config("paper_demo")
-    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+# (S, W, (H, KV, hd) or None for paper_demo's, table width). The benchmark
+# cells' decode shapes: deepseek-coder-33b's 56 heads padded to 64 over 8
+# kv heads of 128 with 4096 / 16 pages, h2o-danube3-4b's 32/8 heads of 120
+# with 768 / 16; a 1+3 verify window; a 1024-row suffix window, which
+# folds fewer kv heads a grid step (at 64/8 heads such a window outgrows
+# VMEM even one kv head a step)
+@pytest.mark.parametrize("S,W,heads,pages", [
+    (8, 1, None, T), (1, 16, None, T),
+    (32, 1, (64, 8, 128), 256), (40, 1, (32, 8, 120), 48),
+    (32, 4, (64, 8, 128), 256), (1, 1024, (32, 8, 128), 256)],
+    ids=["decode", "suffix", "dsc33b-decode", "danube3-decode",
+         "dsc33b-verify", "suffix-1024"])
+def test_paged_attention_compiles(one_chip, S, W, heads, pages):
+    if heads is None:
+        cfg = get_config("paper_demo")
+        heads = (cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim)
+    H, KV, hd = heads
+    kb = pa_kernel.kv_fold(KV, W, H // KV, hd, PS, 2)
+    if W <= 4:
+        assert kb == KV
+    if W >= 1024:
+        assert kb < KV
     bf = jnp.bfloat16
     args = (_spec((S, W, H, hd), bf, one_chip),
             _spec((S, W, KV, hd), bf, one_chip),
             _spec((S, W, KV, hd), bf, one_chip),
-            _spec((S * T + 1, KV, PS, hd), bf, one_chip),
-            _spec((S * T + 1, KV, PS, hd), bf, one_chip),
-            _spec((S, T), jnp.int32, one_chip),
+            _spec((S * pages + 1, KV, PS, hd), bf, one_chip),
+            _spec((S * pages + 1, KV, PS, hd), bf, one_chip),
+            _spec((S, pages), jnp.int32, one_chip),
             _spec((S,), jnp.int32, one_chip),
             _spec((S,), jnp.int32, one_chip))
     compiled = _compile(
