@@ -8,9 +8,10 @@ For each seed: new weights from it, a window of the cell's own traffic
 at its own load, and then, over the same sample of finished requests
 that a run compares, two numbers against the float32 reference: the
 program's widest served-token gap (its lower reading is the largest over
-the seeds) and the fp8 control's (the reference with every matrix
-rounded to float8 e4m3, put in the program's place; its upper reading is
-the smallest over the seeds). One JSON line per seed, then a summary.
+the seeds) and the fp8 control's (the configuration's architecture's
+``Reference``, ``perf/arch/<arch>.py``, with every matrix rounded to
+float8 e4m3, put in the program's place; its upper reading is the
+smallest over the seeds). One JSON line per seed, then a summary.
 The benchmark's own runs do not run this.
 """
 import argparse

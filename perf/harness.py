@@ -230,8 +230,8 @@ class Served:
 
     def _params(self, seed: int):
         import jax
-        p = model.make_params(self.spec, self.cfg.padded_heads,
-                              self.cfg.padded_vocab, seed, self.dev)
+        p = model.arch(self.spec).make_params(self.spec, self.cfg, seed,
+                                              self.dev)
         return jax.block_until_ready(p)
 
     def _placed_params(self) -> List[Any]:
@@ -468,13 +468,13 @@ def max_gap(spec, traffic, params, records, seed, *, control=False,
     which a served token's reference logit lies below the reference's
     best, over a sample of finished requests (none finished: fails).
     With ``control``, returns ``(served, control)`` gaps instead."""
-    from perf.reference import Reference
     picked = sample_finished(records, seed, traffic["check"])
     log(f"perf: comparing {sum(len(r.request.tokens) for r in picked)} "
         f"served tokens of {len(picked)} requests")
     if not picked:
         return (NO_ANSWER, NO_ANSWER) if control else NO_ANSWER
-    ref = Reference(spec, params, generate.longest(traffic) + 1)
+    ref = model.arch(spec).Reference(spec, params,
+                                     generate.longest(traffic) + 1)
     gaps = [ref.served_gaps(r.req.prompt,
                             np.asarray(r.request.tokens, np.int32),
                             control=control) for r in picked]

@@ -1,24 +1,24 @@
-"""A configuration's file, and its weights made from the seed.
+"""A configuration's file, and the architecture module it names.
 
-The weights are the benchmark's, not the program's: one jitted call makes
-the whole parameter tree on the device, in the types the program serves
-(bf16 matrices, float32 norm scales), laid out as the program's dense
-decoder reads it:
+A configuration is ``perf/configs/<name>.json``: the published sizes,
+the program's registry entry and overrides, and ``"arch"``, which names
+``perf/arch/<arch>.py`` as a mix's ``"generator"`` names its generator.
+The architecture module holds everything of one layer stack that the
+benchmark needs, from the seed and the published description alone:
 
-* ``embed``: ``tokens (Vp, d)``, and ``head (d, Vp)`` when untied;
-* ``layers`` (stacked on a leading L axis): ``norm1/norm2.scale (d,)``,
-  ``attn.wq (d, Hp, hd)``, ``attn.wk/wv (d, KV, hd)``, ``attn.wo (Hp, hd,
-  d)``, ``mlp.wi`` (up) and ``mlp.wg`` (gate) ``(d, ff)``, ``mlp.wo
-  (ff, d)``;
-* ``final_norm.scale (d,)``.
-
-``Hp`` is the program's padded query-head count: each kv head's group of
-``Hp / KV`` slots holds its ``H / KV`` real heads first and zero heads
-after them. ``Vp`` is the padded vocabulary; its rows past ``V`` are
-never a token.
+* ``check(spec)``: raise ``ValueError`` if a size it needs is missing;
+* ``make_params(spec, cfg, seed, device)``: the weights, made on
+  ``device`` from ``seed`` in one jitted call, laid out as the program
+  reads them (``cfg`` is the program's ``ModelConfig``);
+* ``Reference(spec, params, length)``: the float32 reference, a
+  ``perf.reference.TeacherForced`` with ``logits(seq, fp8)``;
+* ``prompt_flops(spec, plen)``, ``decode_flops(spec, pos)`` and
+  ``paged_attention_work(spec, pos)``: the work counts the per-layer
+  readers take through ``perf/work.py``.
 """
 from __future__ import annotations
 
+import importlib.util
 import json
 from pathlib import Path
 from typing import Any, Dict
@@ -26,10 +26,38 @@ from typing import Any, Dict
 import numpy as np
 
 CONFIG_DIR = Path(__file__).resolve().parent / "configs"
+ARCH_DIR = Path(__file__).resolve().parent / "arch"
+
+_MODULES: Dict[Path, Any] = {}
 
 
 def load(name: str) -> Dict[str, Any]:
-    return json.loads((CONFIG_DIR / f"{name}.json").read_text())
+    """The configuration's file, checked by its architecture module."""
+    path = CONFIG_DIR / f"{name}.json"
+    spec = json.loads(path.read_text())
+    try:
+        arch(spec).check(spec)
+    except ValueError as exc:
+        raise ValueError(f"{path}: {exc}") from exc
+    return spec
+
+
+def arch(spec: Dict[str, Any]):
+    """The module of the architecture ``spec`` names."""
+    name = spec.get("arch")
+    if not name:
+        raise ValueError(f"no \"arch\" key naming a module in {ARCH_DIR}")
+    path = ARCH_DIR / f"{name}.py"
+    if path in _MODULES:
+        return _MODULES[path]
+    if not path.is_file():
+        raise ValueError(f"no architecture module {path}")
+    mod_spec = importlib.util.spec_from_file_location(f"perf_arch_{name}",
+                                                      path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    _MODULES[path] = mod
+    return mod
 
 
 def program_config(spec: Dict[str, Any]):
@@ -51,59 +79,3 @@ def seed_key(seed: int):
     import jax
     key = jax.random.PRNGKey(np.uint32(seed & 0xFFFFFFFF))
     return jax.random.fold_in(key, np.uint32((seed >> 32) & 0xFFFFFFFF))
-
-
-def make_params(spec: Dict[str, Any], padded_heads: int, padded_vocab: int,
-                seed: int, device):
-    """The parameter tree for ``spec`` from ``seed``, on ``device``."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import SingleDeviceSharding
-
-    d = spec["hidden_size"]
-    ff = spec["intermediate_size"]
-    L = spec["num_hidden_layers"]
-    H, KV = spec["num_attention_heads"], spec["num_key_value_heads"]
-    hd = spec["head_dim"]
-    Hp, Vp = padded_heads, padded_vocab
-    tied = spec["tie_word_embeddings"]
-    mask = np.zeros(Hp, np.float32)
-    mask[real_heads(H, KV, Hp)] = 1.0
-    bf16 = jnp.bfloat16
-
-    def normal(key, shape, std):
-        return (jax.random.normal(key, shape, jnp.float32) * std).astype(bf16)
-
-    def scale(key, n):
-        return 1.0 + 0.1 * jax.random.normal(key, (n,), jnp.float32)
-
-    def layer(key):
-        k = jax.random.split(key, 9)
-        m = jnp.asarray(mask)
-        return {
-            "norm1": {"scale": scale(k[0], d)},
-            "attn": {
-                "wq": normal(k[1], (d, Hp, hd), d ** -0.5)
-                * m[None, :, None].astype(bf16),
-                "wk": normal(k[2], (d, KV, hd), d ** -0.5),
-                "wv": normal(k[3], (d, KV, hd), d ** -0.5),
-                "wo": normal(k[4], (Hp, hd, d), (H * hd) ** -0.5)
-                * m[:, None, None].astype(bf16)},
-            "norm2": {"scale": scale(k[5], d)},
-            "mlp": {"wi": normal(k[6], (d, ff), d ** -0.5),
-                    "wg": normal(k[7], (d, ff), d ** -0.5),
-                    "wo": normal(k[8], (ff, d), ff ** -0.5)},
-        }
-
-    def make(key):
-        k_emb, k_head, k_layers, k_norm = jax.random.split(key, 4)
-        embed = {"tokens": normal(k_emb, (Vp, d), 0.02 if tied else 1.0)}
-        if not tied:
-            embed["head"] = normal(k_head, (d, Vp), d ** -0.5)
-        # one layer at a time: temporaries stay one layer's size
-        layers = jax.lax.map(layer, jax.random.split(k_layers, L))
-        return {"embed": embed, "layers": layers,
-                "final_norm": {"scale": scale(k_norm, d)}}
-
-    return jax.jit(make, out_shardings=SingleDeviceSharding(device))(
-        seed_key(seed))

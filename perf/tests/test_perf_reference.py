@@ -1,9 +1,10 @@
-"""The benchmark's float32 reference against the program's own forward
-pass, on the CPU at toy size: a reduced paper_demo (tied head, 4/2
-heads), a reduced dsc33b_6l shape (untied head, 14 query heads over 2
-kv heads padded to 16 slots, vocabulary 500 padded to 512) and a reduced
-danube3_4b shape (a head size that is no power of two, 30 as 120 is,
-and the registry's sliding window turned off)."""
+"""The dense decoder's float32 reference (``perf/arch/dense.py``)
+against the program's own forward pass, on the CPU at toy size: a
+reduced paper_demo (tied head, 4/2 heads), a reduced dsc33b_6l shape
+(untied head, 14 query heads over 2 kv heads padded to 16 slots,
+vocabulary 500 padded to 512) and a reduced danube3_4b shape (a head
+size that is no power of two, 30 as 120 is, and the registry's sliding
+window turned off)."""
 import dataclasses
 
 import jax
@@ -12,11 +13,13 @@ import numpy as np
 import pytest
 
 from perf import model
-from perf.reference import Reference, fp8_round
+from perf.reference import fp8_round
 from perf.tests import tiny
 
+dense = model.arch({"arch": "dense"})
+
 DSC_SMALL = {
-    "name": "dsc_small", "registry": "deepseek_coder_33b",
+    "name": "dsc_small", "arch": "dense", "registry": "deepseek_coder_33b",
     "overrides": {"n_layers": 2, "d_model": 128, "n_heads": 14,
                   "n_kv_heads": 2, "head_dim": 32, "d_ff": 256,
                   "vocab_size": 500, "norm_eps": 1e-06},
@@ -27,7 +30,7 @@ DSC_SMALL = {
 }
 
 DANUBE_SMALL = {
-    "name": "danube_small", "registry": "h2o_danube3_4b",
+    "name": "danube_small", "arch": "dense", "registry": "h2o_danube3_4b",
     "overrides": {"n_layers": 2, "d_model": 120, "n_heads": 4,
                   "n_kv_heads": 2, "head_dim": 30, "d_ff": 256,
                   "vocab_size": 500, "window": 0, "rope_theta": 100000.0,
@@ -56,18 +59,17 @@ def _program_logits(spec, params, seq):
                               "danube3_small"])
 def test_reference_matches_program_forward(spec):
     cfg = model.program_config(spec)
-    params = model.make_params(spec, cfg.padded_heads, cfg.padded_vocab,
-                               seed=3, device=jax.devices()[0])
+    params = dense.make_params(spec, cfg, seed=3, device=jax.devices()[0])
     seq = np.random.default_rng(0).integers(0, spec["vocab_size"], 37)
     want = _program_logits(spec, params, seq)
-    got = np.asarray(Reference(spec, params, 48).logits(seq))[:37]
+    got = np.asarray(dense.Reference(spec, params, 48).logits(seq))[:37]
     np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
 
 
 def test_padded_heads_are_zero_and_real_heads_are_not():
     cfg = model.program_config(DSC_SMALL)
     assert cfg.padded_heads == 16
-    params = model.make_params(DSC_SMALL, 16, cfg.padded_vocab, seed=1,
+    params = dense.make_params(DSC_SMALL, cfg, seed=1,
                                device=jax.devices()[0])
     wq = np.asarray(params["layers"]["attn"]["wq"], np.float32)
     real = model.real_heads(14, 2, 16)
@@ -79,9 +81,9 @@ def test_padded_heads_are_zero_and_real_heads_are_not():
 
 def test_served_gap_is_zero_for_the_reference_argmax():
     cfg = model.program_config(tiny.SPEC)
-    params = model.make_params(tiny.SPEC, cfg.padded_heads, cfg.padded_vocab,
-                               seed=5, device=jax.devices()[0])
-    ref = Reference(tiny.SPEC, params, 32)
+    params = dense.make_params(tiny.SPEC, cfg, seed=5,
+                               device=jax.devices()[0])
+    ref = dense.Reference(tiny.SPEC, params, 32)
     prompt = np.arange(1, 9, dtype=np.int32)
     seq = list(prompt)
     for _ in range(6):     # greedy decode by the reference itself

@@ -129,11 +129,14 @@ def test_files_dropped_in_are_found_by_name(tmp_path, monkeypatch):
     (tmp_path / "traffic").mkdir()
     (tmp_path / "configs").mkdir()
     (tmp_path / "metrics").mkdir()
+    (tmp_path / "arch").mkdir()
     mix = dict(generate.load("codegen_closed32"), clients=3)
     (tmp_path / "traffic" / "new_mix.json").write_text(json.dumps(mix))
     shutil.copy(generate.TRAFFIC_DIR / "sized.py", tmp_path / "traffic")
-    (tmp_path / "configs" / "new_cfg.json").write_text(
-        json.dumps({"name": "new_cfg", "hidden_size": 1}))
+    (tmp_path / "configs" / "new_cfg.json").write_text(json.dumps(
+        {"name": "new_cfg", "arch": "new_arch", "hidden_size": 1}))
+    (tmp_path / "arch" / "new_arch.py").write_text(
+        "def check(spec):\n    pass\n")
     (tmp_path / "metrics" / "new.metric.py").write_text(
         "def read(run):\n    return run.value * 2\n")
     (tmp_path / "traffic" / "fixed.py").write_text(
@@ -144,9 +147,12 @@ def test_files_dropped_in_are_found_by_name(tmp_path, monkeypatch):
         json.dumps({"generator": "fixed"}))
     monkeypatch.setattr(generate, "TRAFFIC_DIR", tmp_path / "traffic")
     monkeypatch.setattr(model, "CONFIG_DIR", tmp_path / "configs")
+    monkeypatch.setattr(model, "ARCH_DIR", tmp_path / "arch")
     monkeypatch.setattr(harness, "METRICS_DIR", tmp_path / "metrics")
     assert generate.load("new_mix")["clients"] == 3
     assert model.load("new_cfg")["hidden_size"] == 1
+    assert model.arch(model.load("new_cfg")).__file__ == str(
+        tmp_path / "arch" / "new_arch.py")
     assert harness.reader("new.metric")(harness.RunData(value=4)) == 8
     assert harness.reader("new.metric.cell")(harness.RunData(value=1)) == 2
     other = generate.load("other_mix")

@@ -2,7 +2,7 @@
 import copy
 
 SPEC = {
-    "name": "tiny", "registry": "paper_demo",
+    "name": "tiny", "arch": "dense", "registry": "paper_demo",
     "overrides": {"n_layers": 2, "d_model": 128, "n_heads": 4,
                   "n_kv_heads": 2, "head_dim": 32, "d_ff": 256,
                   "vocab_size": 512},

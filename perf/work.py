@@ -1,9 +1,10 @@
-"""Operations and bytes the served work needs, from the configuration's
-published sizes (real query heads, real vocabulary) and the positions of
-the tokens served. Nothing here reads the program's grid or padding.
+"""Operations and bytes the served work needs, and the chip's peaks.
 
-A decode token at position ``pos`` (its query is token ``pos``; the
-cache holds positions ``0 .. pos-1``) attends over ``pos + 1`` keys.
+The counts come from the configuration's architecture module
+(``perf/arch/<arch>.py``, named by its ``"arch"``), from the published
+sizes and the positions of the tokens served; nothing here reads the
+program's grid or padding. The per-layer readers reach them as
+``run.work``.
 """
 from __future__ import annotations
 
@@ -11,7 +12,8 @@ import json
 from pathlib import Path
 from typing import Any, Dict, Tuple
 
-BF16 = 2
+from perf import model
+
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
 
 
@@ -28,58 +30,20 @@ def peaks(device_kind: str) -> Dict[str, Any]:
     return table[device_kind]
 
 
-def dims(spec: Dict[str, Any]) -> Dict[str, int]:
-    return {"d": spec["hidden_size"], "ff": spec["intermediate_size"],
-            "L": spec["num_hidden_layers"],
-            "H": spec["num_attention_heads"],
-            "KV": spec["num_key_value_heads"], "hd": spec["head_dim"],
-            "V": spec["vocab_size"]}
-
-
-def layer_matmul_params(spec: Dict[str, Any]) -> int:
-    """Weights one token multiplies through in one layer."""
-    m = dims(spec)
-    d, H, KV, hd = m["d"], m["H"], m["KV"], m["hd"]
-    return d * H * hd + 2 * d * KV * hd + H * hd * d + 3 * d * m["ff"]
-
-
-def attention_flops(spec: Dict[str, Any], keys: int) -> int:
-    """QK and PV of one query over ``keys`` keys, all layers."""
-    m = dims(spec)
-    return 4 * m["L"] * m["H"] * m["hd"] * keys
-
-
-def head_flops(spec: Dict[str, Any]) -> int:
-    m = dims(spec)
-    return 2 * m["d"] * m["V"]
-
-
 def prompt_flops(spec: Dict[str, Any], plen: int) -> int:
-    """Prefill of a ``plen``-token prompt: every layer on every token,
-    causal attention at each token's own length, and the head on the
-    last token only (the one whose logits give the first output)."""
-    m = dims(spec)
-    return (2 * m["L"] * layer_matmul_params(spec) * plen
-            + attention_flops(spec, plen * (plen + 1) // 2)
-            + head_flops(spec))
+    """Prefill of a ``plen``-token prompt, up to its first output."""
+    return model.arch(spec).prompt_flops(spec, plen)
 
 
 def decode_flops(spec: Dict[str, Any], pos: int) -> int:
     """One decode token at position ``pos``."""
-    m = dims(spec)
-    return (2 * m["L"] * layer_matmul_params(spec)
-            + attention_flops(spec, pos + 1) + head_flops(spec))
+    return model.arch(spec).decode_flops(spec, pos)
 
 
 def paged_attention_work(spec: Dict[str, Any], pos: int) -> Tuple[int, int]:
     """(flops, bytes) the paged-attention kernel needs for one decode
-    token at ``pos``, all layers: read the ``pos`` cached k/v rows and
-    the new k/v row, write the new row, read q and write o."""
-    m = dims(spec)
-    L, H, KV, hd = m["L"], m["H"], m["KV"], m["hd"]
-    row = 2 * KV * hd * BF16                 # one position's k and v
-    nbytes = L * (row * pos + 2 * row + 2 * H * hd * BF16)
-    return attention_flops(spec, pos + 1), nbytes
+    token at ``pos``, all layers."""
+    return model.arch(spec).paged_attention_work(spec, pos)
 
 
 def roofline_share(flops: float, nbytes: float, seconds: float,
@@ -88,4 +52,3 @@ def roofline_share(flops: float, nbytes: float, seconds: float,
     least = max(flops / peak["bf16_flops_per_s"],
                 nbytes / peak["hbm_bytes_per_s"])
     return 100.0 * least / seconds
-
